@@ -4,12 +4,16 @@
 
 Emits per-benchmark CSVs to bench_out/ and a ``name,us_per_call,derived``
 summary to stdout (derived = the benchmark's headline metric/CSV path).
+A failed benchmark does not stop the others, but the run exits non-zero.
+Compiled programs persist in JAX's compile cache (``repro.compile_cache``).
 """
 from __future__ import annotations
 
 import argparse
+import sys
 import time
 import traceback
+from pathlib import Path
 
 
 def main() -> None:
@@ -18,6 +22,8 @@ def main() -> None:
     ap.add_argument("--only", default=None)
     args = ap.parse_args()
 
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache(Path(__file__).resolve().parents[1])
     from . import (bench_ablation, bench_cache, bench_qps_recall, bench_quant,
                    bench_selectivity, bench_serve_backends,
                    bench_verification)
@@ -39,6 +45,7 @@ def main() -> None:
         ("linear_model_tab6", bench_verification.run_linear_model),
     ]
     print("name,us_per_call,derived")
+    failed = []
     for name, fn in benches:
         if args.only and args.only not in name:
             continue
@@ -50,6 +57,9 @@ def main() -> None:
         except Exception as e:
             traceback.print_exc()
             print(f"{name},-1,FAILED:{type(e).__name__}")
+            failed.append(name)
+    if failed:
+        sys.exit(f"{len(failed)} benchmark(s) failed: {', '.join(failed)}")
 
 
 if __name__ == "__main__":

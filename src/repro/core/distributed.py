@@ -24,7 +24,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from . import exclusion
 from . import filters as F
@@ -301,11 +300,11 @@ def make_serve_fns(mesh: Mesh, cfg: SearchConfig, *, ef_sel: int | None = None,
         tot = jax.lax.psum(tot, model_axis)
         return cnt / tot
 
-    estimate = jax.jit(shard_map(
+    estimate = jax.jit(jax.shard_map(
         _estimate, mesh=mesh,
         in_specs=(dspecs, pspec_each),
         out_specs=P(qspec[0]),
-        check_rep=False))
+        check_vma=False))
 
     # -- graph route ----------------------------------------------------------
     if cfg.graph_quant is not None and cfg.graph_quant != quant:
@@ -345,20 +344,20 @@ def make_serve_fns(mesh: Mesh, cfg: SearchConfig, *, ef_sel: int | None = None,
         return _graph_from_phat(db, queries, programs,
                                 _estimate(db, programs), valid)
 
-    serve_graph = jax.jit(shard_map(
+    serve_graph = jax.jit(jax.shard_map(
         _serve_graph, mesh=mesh,
         in_specs=(dspecs, qspec, pspec_each, vspec),
         out_specs=(qspec, qspec),
-        check_rep=False))
+        check_vma=False))
 
     # same route with the selectivity estimate supplied by the caller (the
     # router already ran it to take the routing decision -- don't pay the
     # O(B x sample) evaluation twice per batch)
-    serve_graph_phat = jax.jit(shard_map(
+    serve_graph_phat = jax.jit(jax.shard_map(
         _graph_from_phat, mesh=mesh,
         in_specs=(dspecs, qspec, pspec_each, P(qspec[0]), vspec),
         out_specs=(qspec, qspec),
-        check_rep=False))
+        check_vma=False))
 
     # -- brute route -----------------------------------------------------------
     def _serve_brute(db, queries, programs, valid):
@@ -377,11 +376,11 @@ def make_serve_fns(mesh: Mesh, cfg: SearchConfig, *, ef_sel: int | None = None,
         d, i = _merge_topk(d, gids, cfg.k, model_axis)
         return jnp.where(jnp.isfinite(d), i, -1), d
 
-    serve_brute = jax.jit(shard_map(
+    serve_brute = jax.jit(jax.shard_map(
         _serve_brute, mesh=mesh,
         in_specs=(dspecs, qspec, pspec_each, vspec),
         out_specs=(qspec, qspec),
-        check_rep=False))
+        check_vma=False))
 
     fns = {"estimate": estimate, "serve_graph": serve_graph,
            "serve_graph_phat": serve_graph_phat, "serve_brute": serve_brute,
@@ -419,11 +418,11 @@ def make_serve_fns(mesh: Mesh, cfg: SearchConfig, *, ef_sel: int | None = None,
             d, i = _merge_topk(d, gids, cfg.k, model_axis)
             return jnp.where(jnp.isfinite(d), i, -1), d
 
-        fns["serve_brute_pq"] = jax.jit(shard_map(
+        fns["serve_brute_pq"] = jax.jit(jax.shard_map(
             _serve_brute_pq, mesh=mesh,
             in_specs=(dspecs, qspec, pspec_each, vspec),
             out_specs=(qspec, qspec),
-            check_rep=False))
+            check_vma=False))
 
     return fns
 

@@ -24,6 +24,7 @@ import numpy as np
 from . import filters as F
 
 INF = jnp.inf
+HIGHEST = jax.lax.Precision.HIGHEST
 
 
 def pad_db(vectors: np.ndarray, norms: np.ndarray, ints: np.ndarray,
@@ -56,8 +57,9 @@ def prefbf_topk(vectors, norms, ints, floats, queries, programs, *,
     """
     if use_pallas:
         from ..kernels.filtered_topk import ops as ft_ops
+        # the scan chunk becomes the kernel's n-tile; keep it VMEM-sized
         return ft_ops.filtered_topk(vectors, norms, ints, floats, queries,
-                                    programs, k=k, block_n=chunk,
+                                    programs, k=k, block_n=min(chunk, 512),
                                     valid=valid)
 
     n, d = vectors.shape
@@ -79,7 +81,9 @@ def prefbf_topk(vectors, norms, ints, floats, queries, programs, *,
         # deferred to the final (B, k) rows after the scan.
         best_d, best_i = carry
         v, nn, ii, ff, start = xs
-        dot = queries @ v.T                                  # (B, chunk) MXU
+        # f32 contraction: the brute route is exact (on TPU the default
+        # precision would round both operands to bf16)
+        dot = jnp.matmul(queries, v.T, precision=HIGHEST)     # (B, chunk) MXU
         d2 = jnp.maximum(nn[None, :] + qn[:, None] - 2.0 * dot, 0.0)
         mask = F.eval_program_batched(programs, ii, ff, xp=jnp)  # (B, chunk)
         d2 = jnp.where(mask, d2, INF)

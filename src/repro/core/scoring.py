@@ -245,7 +245,7 @@ class SqScorer:
         b, m0, d = c.shape
         quad = jax.lax.dot_general(
             (c * c).reshape(b * m0, d), state["w2"],
-            (((1,), (0,)), ((), ())),
+            (((1,), (0,)), ((), ())), precision=jax.lax.Precision.HIGHEST,
             preferred_element_type=jnp.float32).reshape(b, m0)
         lin = jnp.sum(c * state["w_lin"][:, None, :], axis=-1)
         d2 = quad + lin + state["const"][:, None]

@@ -2,7 +2,7 @@
 
 Each kernel package ships three files:
   kernel.py -- pl.pallas_call body with explicit BlockSpec VMEM tiling
-  ops.py    -- jit'd public wrapper (padding, program flattening, interpret
+  ops.py    -- jit'd public wrapper (padding, kernel layouts, interpret
                auto-detection: interpret=True on CPU, compiled on TPU)
   ref.py    -- pure-jnp oracle used by the shape/dtype sweep tests
 
@@ -21,5 +21,10 @@ import jax
 
 
 def default_interpret() -> bool:
-    """Pallas interpret mode on CPU (validation), compiled on TPU (target)."""
-    return jax.default_backend() != "tpu"
+    """Pallas interpret mode on CPU (validation), compiled on TPU (target).
+    Any other platform is an error: the kernels are written for the TPU."""
+    platform = jax.default_backend()
+    if platform not in ("cpu", "tpu"):
+        raise RuntimeError(f"Pallas kernels run compiled on TPU or "
+                           f"interpreted on CPU, not on {platform!r}")
+    return platform == "cpu"

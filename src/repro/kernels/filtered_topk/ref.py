@@ -1,6 +1,7 @@
 """Pure-jnp oracle for the filtered_topk kernel."""
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 from ...core import filters as F
@@ -16,7 +17,9 @@ def filtered_topk_ref(queries, vectors, norms, ints, floats, programs, dvec,
     rows to BIG; exclusion mode adds D per query (Eq. 2).  Rows with
     norm >= BIG (padding) never win."""
     qn = jnp.sum(queries * queries, axis=-1)
-    d2 = norms[None, :] + qn[:, None] - 2.0 * (queries @ vectors.T)
+    d2 = (norms[None, :] + qn[:, None]
+          - 2.0 * jnp.matmul(queries, vectors.T,
+                             precision=jax.lax.Precision.HIGHEST))
     dist = jnp.sqrt(jnp.maximum(d2, 0.0))
     mask = F.eval_program_batched(programs, ints, floats, xp=jnp)  # (B, N)
     if exclude:

@@ -14,26 +14,26 @@ Per (i, j) step, entirely in VMEM:
     becomes a (bn, K) one-hot and contracts with the (bq, K) LUT slice on the
     MXU -- a gather expressed as arithmetic, since TPU Pallas has no
     in-kernel vector gather,
-  * evaluate the DNF filter program on the attribute rows (shared helper
-    from filtered_topk) and mask failing + padded rows (norm >= BIG) to BIG,
-  * merge into the running (bq, R) top-R scratch (R = rerank * k; the exact
-    float32 re-rank happens outside, in quant/adc.py).
+  * evaluate the DNF filter program on the lane-dense attribute planes
+    (shared helper from filtered_topk) and mask failing + padded rows
+    (norm >= BIG) to BIG,
+  * merge into the running (bq, Rp) top-R scratch (R = rerank * k, Rp its
+    lane-aligned width; the exact float32 re-rank happens outside, in
+    quant/adc.py).
 
-VMEM working set per step: bq*M*K + bn*M + bn*K + bq*bn + bq*R floats;
-defaults (bq, bn, M, K) = (128, 512, 8, 256) stay well under 16 MB.
+VMEM working set per step: bq*M*K + bn*M + bn*K + bq*(Rp+bn) floats;
+defaults (bq, bn, M, K) = (128, 512, 8, 256) stay well under 16 MB (the
+wrapper shrinks bq for wide LUTs).
 
-The graph-route sibling ``pq_adc_gather_pallas`` is **row-batched**: one
-sequential pass per bq-query tile stages the whole (bq, M0) gathered
-neighbor code block into VMEM scratch (one uint8 row DMA per inner grid
-step, picked by the scalar-prefetch index_map), then scores all bq*M0 rows
-against the LUT tile with the same M one-hot MXU matmuls the full-scan
-kernel uses and slices each query's own M0 columns off the result cube.
-That replaces the former per-(query, neighbor)-cell launch whose LUT lookup
-ran as M*K scalar fmas on the VPU -- the MXU form does bq x redundant math
-(every query scores every staged row) but turns ~bq*M0*M*K scalar ops per
-tile into M dense (bq, K) x (K, bq*M0) contractions, which is the shape the
-hardware is actually fast at.  Keep bq small (default 8, one MXU sublane
-block): the redundancy factor is exactly bq.
+The graph-route sibling ``pq_adc_gather_pallas`` scores per-query neighbor
+blocks.  The (B, M0) neighbor code rows are gathered by XLA in the wrapper
+(M bytes per row; a one-row block would break the TPU's (8, 128) block
+tiling), so each grid step holds a bq-query tile's whole (bq*M0, M) code
+block.  It is scored against the LUT tile with the same M one-hot MXU
+matmuls the full-scan kernel uses -- every query scores every staged row --
+and a selection matmul keeps each query's own M0 columns.  The MXU form
+does bq x redundant math but turns the LUT lookups into M dense
+(bq, K) x (K, bq*M0) contractions; keep bq at one sublane group (8).
 """
 from __future__ import annotations
 
@@ -44,7 +44,26 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..filtered_topk.kernel import BIG, _eval_program_tile, _topk_merge
+from ..filtered_topk.kernel import (BIG, HIGHEST, LANES, eval_program,
+                                    fold_own_rows, selection_matrix,
+                                    topk_merge)
+
+
+def _adc_block(lut, codes, m: int, ksub: int):
+    """(bq, M*K) LUT tile x (R, M) int32 code rows -> (bq, R) ADC sums.
+
+    A bf16 LUT multiplies a bf16 one-hot (exact products, f32 accumulate);
+    an f32 LUT contracts at f32 precision, so both equal the table sums."""
+    kcols = jax.lax.broadcasted_iota(jnp.int32, (1, ksub), 1)
+    exact = HIGHEST if lut.dtype == jnp.float32 else None
+    acc = jnp.zeros((lut.shape[0], codes.shape[0]), jnp.float32)
+    for mm in range(m):                 # static unroll: M is small (<= 64)
+        oh = (codes[:, mm:mm + 1] == kcols).astype(lut.dtype)    # (R, K)
+        acc = acc + jax.lax.dot_general(
+            lut[:, mm * ksub:(mm + 1) * ksub], oh,
+            (((1,), (1,)), ((), ())), precision=exact,
+            preferred_element_type=jnp.float32)                   # MXU
+    return acc
 
 
 def _kernel(lut_ref, c_ref, n_ref, ai_ref, af_ref, valid_ref, imask_ref,
@@ -54,134 +73,94 @@ def _kernel(lut_ref, c_ref, n_ref, ai_ref, af_ref, valid_ref, imask_ref,
 
     @pl.when(j == 0)
     def _init():
-        bd_ref[...] = jnp.full_like(bd_ref, BIG)
-        bi_ref[...] = jnp.full_like(bi_ref, -1)
+        bd_ref[...] = jnp.full(bd_ref.shape, BIG, jnp.float32)
+        bi_ref[...] = jnp.full(bi_ref.shape, -1, jnp.int32)
 
-    lut = lut_ref[...].astype(jnp.float32)   # (bq, M*K); accepts bf16 tables
-    codes = c_ref[...].astype(jnp.int32)     # (bn, M) uint8 -> in-register
-    kcols = jax.lax.broadcasted_iota(jnp.int32, (1, ksub), 1)
-    acc = jnp.zeros((lut.shape[0], bn), jnp.float32)
-    for mm in range(m):                 # static unroll: M is small (<= 32)
-        oh = (codes[:, mm:mm + 1] == kcols).astype(jnp.float32)   # (bn, K)
-        acc = acc + jax.lax.dot_general(
-            lut[:, mm * ksub:(mm + 1) * ksub], oh,
-            (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)                   # MXU
+    # (bn, M) uint8 -> int32 in-register
+    acc = _adc_block(lut_ref[...], c_ref[...].astype(jnp.int32), m, ksub)
 
-    mask = _eval_program_tile(valid_ref[...], imask_ref[...], flo_ref[...],
-                              fhi_ref[...], ai_ref[...], af_ref[...])
-    ok = mask & (n_ref[...] < BIG)[None, :]   # padded rows carry BIG norms
+    ai, af = ai_ref[...], af_ref[...]
+    mask = eval_program(valid_ref[...], imask_ref[...], flo_ref[...],
+                        fhi_ref[...],
+                        [ai[c:c + 1, :] for c in range(ai.shape[0])],
+                        [af[c:c + 1, :] for c in range(af.shape[0])])
+    ok = mask & (n_ref[...] < BIG)            # padded rows carry BIG norms
     dist = jnp.minimum(jnp.where(ok, acc, BIG), BIG)
 
-    ids = (j * bn + jnp.arange(bn, dtype=jnp.int32))[None, :]
-    ids = jnp.broadcast_to(ids, dist.shape)
-
-    bd, bi = _topk_merge(bd_ref[...], bi_ref[...], dist, ids, r)
+    ids = j * bn + jax.lax.broadcasted_iota(jnp.int32, dist.shape, 1)
+    bd, bi = topk_merge(bd_ref[...], bi_ref[...], dist, ids, r)
     bd_ref[...] = bd
     bi_ref[...] = bi
-    od_ref[...] = bd
-    oi_ref[...] = bi
+
+    @pl.when(j == pl.num_programs(1) - 1)
+    def _out():
+        od_ref[...] = bd
+        oi_ref[...] = bi
 
 
-def _gather_kernel(idx_ref, lut_ref, c_ref, ids_ref, o_ref, stage_ref,
-                   *, bq: int, m0: int, m: int, ksub: int):
-    """Row-batched gather scoring: stage bq*M0 code rows, then M MXU matmuls.
+def _gather_kernel(lut_ref, c_ref, ids_ref, sel_ref, o_ref,
+                   *, m0: int, m: int, ksub: int):
+    """Score one bq-query tile's gathered (bq*M0, M) code block.
 
-    The inner grid axis walks the bq-query tile's flattened (bq*M0,) neighbor
-    list; each step's code row arrives via the scalar-prefetch index_map (the
-    paged-attention indirection gather_distance uses) and is parked in the
-    VMEM ``stage_ref`` block.  The last step scores the whole staged block
-    against the LUT tile exactly like the full-scan kernel -- per subspace a
-    (bq*M0, K) one-hot contracts with the (bq, K) LUT slice on the MXU --
-    and extracts each query's own M0-slice from the (bq, bq, M0) result cube
-    (row j of the stage belongs to query j // M0).
-    """
-    j = pl.program_id(1)
-    r0 = bq * m0
-
-    # one uint8 row DMA per step: M bytes of HBM traffic per neighbor
-    stage_ref[pl.ds(j, 1), :] = c_ref[...].astype(jnp.int32)
-
-    @pl.when(j == r0 - 1)
-    def _score():
-        codes = stage_ref[...]                     # (bq*M0, M)
-        lut = lut_ref[...].astype(jnp.float32)     # (bq, M*K); accepts bf16
-        kcols = jax.lax.broadcasted_iota(jnp.int32, (1, ksub), 1)
-        acc = jnp.zeros((bq, r0), jnp.float32)
-        for mm in range(m):             # static unroll: M is small (<= 32)
-            oh = (codes[:, mm:mm + 1] == kcols).astype(jnp.float32)
-            acc = acc + jax.lax.dot_general(
-                lut[:, mm * ksub:(mm + 1) * ksub], oh,
-                (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32)               # MXU
-        # every query scored every staged row (bq x redundant, MXU-cheap);
-        # keep the diagonal blocks of the (bq, bq, M0) cube
-        cube = acc.reshape(bq, bq, m0)
-        qi = jax.lax.broadcasted_iota(jnp.int32, (bq, bq), 0)
-        qj = jax.lax.broadcasted_iota(jnp.int32, (bq, bq), 1)
-        eye = (qi == qj).astype(jnp.float32)
-        out = jnp.sum(cube * eye[:, :, None], axis=1)             # (bq, M0)
-        o_ref[...] = jnp.where(ids_ref[...] < 0, BIG, out)
+    The (bq, bq*M0) all-pairs ADC matrix is folded to each query's own M0
+    rows (``fold_own_rows``)."""
+    acc = _adc_block(lut_ref[...], c_ref[...], m, ksub)      # (bq, bq*M0)
+    out = fold_own_rows(acc, sel_ref[...], m0)
+    o_ref[...] = jnp.where(ids_ref[...] < 0, BIG, out)
 
 
 def pq_adc_gather_pallas(nbr_ids, luts, codes, *, block_q: int,
                          interpret: bool):
-    """Row-batched block-gather ADC scoring (graph-route sibling of
-    pq_adc_pallas).
+    """Block-gather ADC scoring (graph-route sibling of pq_adc_pallas).
 
     nbr_ids (B, M0) int32 (-1 pad); luts (B, M*K) flattened (f32 or bf16);
-    codes (N, M) uint8 -- NOT widened host-side, so each gathered row
-    streams M bytes.  B must be a multiple of block_q (ops.py pads).
-    Returns adc_d2 (B, M0) float32 with BIG at padding.
+    codes (B*M0, M) int32 -- the neighbor code rows, already gathered (row
+    b*M0 + j holds neighbor j of query b).  B must be a multiple of
+    block_q (ops.py pads).  Returns adc_d2 (B, M0) float32 with BIG at
+    padding.
     """
     b, m0 = nbr_ids.shape
-    n, m = codes.shape
+    m = codes.shape[1]
     mk = luts.shape[1]
     ksub = mk // m
     bq = block_q
-    assert b % bq == 0
+    assert b % bq == 0 and codes.shape[0] == b * m0
+    rows = bq * m0
 
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(b // bq, bq * m0),
+    return pl.pallas_call(
+        functools.partial(_gather_kernel, m0=m0, m=m, ksub=ksub),
+        grid=(b // bq,),
         in_specs=[
-            pl.BlockSpec((bq, mk), lambda i, j, idx: (i, 0)),     # LUT tile
-            pl.BlockSpec((1, m),                                  # code[gather]
-                         lambda i, j, idx: (
-                             jnp.maximum(idx[i * bq + j // m0, j % m0], 0),
-                             0)),
-            pl.BlockSpec((bq, m0), lambda i, j, idx: (i, 0)),     # raw ids
+            pl.BlockSpec((bq, mk), lambda i: (i, 0)),            # LUT tile
+            pl.BlockSpec((rows, m), lambda i: (i, 0)),           # code rows
+            pl.BlockSpec((bq, m0), lambda i: (i, 0)),            # raw ids
+            pl.BlockSpec((rows, m0), lambda i: (0, 0)),          # selection
         ],
-        out_specs=[
-            pl.BlockSpec((bq, m0), lambda i, j, idx: (i, 0)),
-        ],
-        scratch_shapes=[
-            # staged gathered code rows for the whole query tile
-            pltpu.VMEM((bq * m0, m), jnp.int32),
-        ],
-    )
-    (out,) = pl.pallas_call(
-        functools.partial(_gather_kernel, bq=bq, m0=m0, m=m, ksub=ksub),
-        grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((b, m0), jnp.float32)],
+        out_specs=pl.BlockSpec((bq, m0), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((b, m0), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
         interpret=interpret,
-    )(nbr_ids, luts, codes, nbr_ids)
-    return out
+    )(luts, codes, nbr_ids, selection_matrix(rows, m0))
 
 
 def pq_adc_pallas(luts, codes, norms, ints, floats, programs, *, r: int,
                   block_q: int, block_n: int, interpret: bool):
-    """Launch the kernel.  All shapes must already be padded to block
-    multiples (ops.py does this).  luts (B, M*K) flattened;
-    returns (adc_d2 (B, R), ids (B, R))."""
+    """Launch the kernel.  Inputs arrive in kernel layout, padded to block
+    multiples (ops.py does both): luts (B, M*K) flattened, codes (N, M),
+    norms (1, N), ints (mi, N), floats (mf, N), programs as
+    filtered_topk_pallas takes them.  Returns (adc_d2 (B, Rp), ids (B, Rp)),
+    Rp = r rounded up to a lane multiple."""
     b, mk = luts.shape
     n, m = codes.shape
     ksub = mk // m
     bq, bn = block_q, block_n
     assert b % bq == 0 and n % bn == 0
+    rp = -(-r // LANES) * LANES
     w = programs["valid"].shape[1]
-    mi = ints.shape[1]
-    mf = floats.shape[1]
+    wi = programs["imask"].shape[1]
+    wf = programs["flo"].shape[1]
+    mi, mf = ints.shape[0], floats.shape[0]
     grid = (b // bq, n // bn)
 
     kern = functools.partial(_kernel, r=r, bn=bn, m=m, ksub=ksub)
@@ -191,27 +170,29 @@ def pq_adc_pallas(luts, codes, norms, ints, floats, programs, *, r: int,
         in_specs=[
             pl.BlockSpec((bq, mk), lambda i, j: (i, 0)),         # LUTs
             pl.BlockSpec((bn, m), lambda i, j: (j, 0)),          # codes
-            pl.BlockSpec((bn,), lambda i, j: (j,)),              # norms
-            pl.BlockSpec((bn, mi), lambda i, j: (j, 0)),         # attrs int
-            pl.BlockSpec((bn, mf), lambda i, j: (j, 0)),         # attrs float
+            pl.BlockSpec((1, bn), lambda i, j: (0, j)),          # norms
+            pl.BlockSpec((mi, bn), lambda i, j: (0, j)),         # attrs int
+            pl.BlockSpec((mf, bn), lambda i, j: (0, j)),         # attrs float
             pl.BlockSpec((bq, w), lambda i, j: (i, 0)),          # valid
-            pl.BlockSpec((bq, w, mi), lambda i, j: (i, 0, 0)),   # imask
-            pl.BlockSpec((bq, w, mf), lambda i, j: (i, 0, 0)),   # flo
-            pl.BlockSpec((bq, w, mf), lambda i, j: (i, 0, 0)),   # fhi
+            pl.BlockSpec((bq, wi), lambda i, j: (i, 0)),         # imask
+            pl.BlockSpec((bq, wf), lambda i, j: (i, 0)),         # flo
+            pl.BlockSpec((bq, wf), lambda i, j: (i, 0)),         # fhi
         ],
         out_specs=[
-            pl.BlockSpec((bq, r), lambda i, j: (i, 0)),
-            pl.BlockSpec((bq, r), lambda i, j: (i, 0)),
+            pl.BlockSpec((bq, rp), lambda i, j: (i, 0)),
+            pl.BlockSpec((bq, rp), lambda i, j: (i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b, r), jnp.float32),
-            jax.ShapeDtypeStruct((b, r), jnp.int32),
+            jax.ShapeDtypeStruct((b, rp), jnp.float32),
+            jax.ShapeDtypeStruct((b, rp), jnp.int32),
         ],
         scratch_shapes=[
             # running top-R state lives in VMEM across the sequential n-axis
-            pltpu.VMEM((bq, r), jnp.float32),
-            pltpu.VMEM((bq, r), jnp.int32),
+            pltpu.VMEM((bq, rp), jnp.float32),
+            pltpu.VMEM((bq, rp), jnp.int32),
         ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(luts, codes, norms, ints, floats, programs["valid"],
       programs["imask"], programs["flo"], programs["fhi"])

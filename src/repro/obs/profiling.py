@@ -37,11 +37,7 @@ def kernel_annotations_enabled() -> bool:
 
 
 def annotate(name: str):
-    """A TraceAnnotation context for ``name`` (nullcontext when disabled or
-    when the installed jax lacks the profiler API)."""
+    """A TraceAnnotation context for ``name`` (nullcontext when disabled)."""
     if not _KERNEL_ANNOTATIONS:
         return nullcontext()
-    try:
-        return jax.profiler.TraceAnnotation(name)
-    except Exception:  # pragma: no cover - profiler backend unavailable
-        return nullcontext()
+    return jax.profiler.TraceAnnotation(name)

@@ -23,6 +23,7 @@ import jax.numpy as jnp
 from ..core import filters as F
 
 INF = jnp.inf
+HIGHEST = jax.lax.Precision.HIGHEST
 
 
 def build_luts(centroids, queries):
@@ -42,7 +43,7 @@ def build_luts(centroids, queries):
     qs = queries.reshape(b, m, dsub)
     qn = jnp.sum(qs * qs, axis=-1)            # (B, M)
     cn = jnp.sum(centroids * centroids, -1)   # (M, K)
-    dot = jnp.einsum("bmd,mkd->bmk", qs, centroids)
+    dot = jnp.einsum("bmd,mkd->bmk", qs, centroids, precision=HIGHEST)
     return jnp.maximum(qn[:, :, None] + cn[None, :, :] - 2.0 * dot, 0.0)
 
 
@@ -169,7 +170,8 @@ def sq_prefbf_topk(codes, lo, scale, norms, ints, floats, queries, programs,
         c, nn, ii, ff, start = xs
         deq = c.astype(jnp.float32) * scale[None, :] + lo[None, :]
         dn = jnp.sum(deq * deq, axis=-1)                     # (chunk,)
-        d2 = dn[None, :] + qn[:, None] - 2.0 * (queries @ deq.T)
+        d2 = (dn[None, :] + qn[:, None]
+              - 2.0 * jnp.matmul(queries, deq.T, precision=HIGHEST))
         d2 = jnp.maximum(d2, 0.0)
         mask = F.eval_program_batched(programs, ii, ff, xp=jnp)
         ok = mask & jnp.isfinite(nn)[None, :]

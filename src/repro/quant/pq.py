@@ -25,6 +25,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+HIGHEST = jax.lax.Precision.HIGHEST
+
 
 @dataclass
 class PQCodebook:
@@ -92,9 +94,12 @@ def _pad_split(x: np.ndarray | jnp.ndarray, m: int, dsub: int):
 # k-means (one subspace; vmapped over M)
 # ---------------------------------------------------------------------------
 def _assign(x, c):
-    """(n, d), (k, d) -> (n,) nearest-centroid ids (squared L2)."""
+    """(n, d), (k, d) -> (n,) nearest-centroid ids (squared L2).  The
+    dots contract at f32 (on TPU the default precision would round both
+    operands to bf16 and pick different centroids than the CPU does)."""
     d2 = (jnp.sum(x * x, axis=1)[:, None]
-          - 2.0 * (x @ c.T) + jnp.sum(c * c, axis=1)[None, :])
+          - 2.0 * jnp.matmul(x, c.T, precision=HIGHEST)
+          + jnp.sum(c * c, axis=1)[None, :])
     return jnp.argmin(d2, axis=1)
 
 
@@ -102,7 +107,7 @@ def _lloyd_step(c, x, k: int):
     a = _assign(x, c)
     oh = jax.nn.one_hot(a, k, dtype=jnp.float32)        # (n, k)
     cnt = jnp.sum(oh, axis=0)                            # (k,)
-    sums = oh.T @ x                                      # (k, d) MXU
+    sums = jnp.matmul(oh.T, x, precision=HIGHEST)        # (k, d) MXU
     # empty clusters keep their previous centroid (no respawn: deterministic)
     return jnp.where(cnt[:, None] > 0, sums / jnp.maximum(cnt, 1.0)[:, None], c)
 
@@ -133,14 +138,21 @@ def train_pq(vectors: np.ndarray, m: int = 8, nbits: int = 8, *,
     xs = _pad_split(np.asarray(vectors, np.float32), m, dsub)  # (n, m, dsub)
     xs = jnp.transpose(xs, (1, 0, 2))                          # (m, n, dsub)
     keys = jax.random.split(jax.random.PRNGKey(seed), m)
-    cents = jax.vmap(lambda x, kk: _kmeans(x, kk, k=k, iters=iters))(xs, keys)
+    # one subspace at a time (lax.map, not vmap): on TPU v5e the vmapped
+    # k-means and the vmapped 2^16-row assignment return wrong centroids and
+    # codes, while the per-subspace program matches the CPU
+    cents = jax.lax.map(lambda a: _kmeans(a[0], a[1], k=k, iters=iters),
+                        (xs, keys))
     return PQCodebook(np.asarray(cents, np.float32), dim=d)
 
 
-@partial(jax.jit, static_argnames=())
+@jax.jit
 def _encode_chunk(xs, centroids):
-    """xs (n, m, dsub), centroids (m, k, dsub) -> codes (n, m) int32."""
-    return jax.vmap(_assign, in_axes=(1, 0), out_axes=1)(xs, centroids)
+    """xs (n, m, dsub), centroids (m, k, dsub) -> codes (n, m) int32, one
+    subspace at a time (see train_pq)."""
+    codes = jax.lax.map(lambda a: _assign(*a),
+                        (jnp.transpose(xs, (1, 0, 2)), centroids))
+    return codes.T
 
 
 def encode(cb: PQCodebook | SQCodebook, vectors: np.ndarray,

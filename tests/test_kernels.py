@@ -72,6 +72,30 @@ def test_filtered_topk_exclusion_mode():
     assert (np.asarray(ids) == np.asarray(ri)).mean() > 0.99
 
 
+def test_filtered_topk_schema_without_float_columns():
+    """The kernels' 2-D program tables stay non-empty for a schema with no
+    float column (one always-passing column stands in)."""
+    schema = F.Schema((F.ColumnSpec("i0", "int", 10),))
+    rng = np.random.default_rng(4)
+    n, d, b = 600, 16, 4
+    vecs = jnp.asarray(rng.normal(size=(n, d)).astype(np.float32))
+    norms = jnp.sum(vecs * vecs, axis=-1)
+    attrs = random_attributes(schema, n, seed=5)
+    assert attrs.floats.shape == (n, 0)
+    ints, floats = jnp.asarray(attrs.ints), jnp.asarray(attrs.floats)
+    flts = [F.Equality("i0", 3), F.Inclusion("i0", [1, 2]), F.TrueFilter(),
+            F.Not(F.Equality("i0", 7))]
+    progs = {k: jnp.asarray(v) for k, v in
+             stack_programs([compile_filter(f, schema) for f in flts]).items()}
+    qs = jnp.asarray(rng.normal(size=(b, d)).astype(np.float32))
+    ids, dd = ft_ops.filtered_topk(vecs, norms, ints, floats, qs, progs, k=8)
+    rd, ri = ft_ref.filtered_topk_ref(qs, vecs, norms, ints, floats, progs,
+                                      jnp.zeros((b,)), k=8, exclude=False)
+    np.testing.assert_allclose(np.asarray(dd), np.asarray(rd), rtol=1e-5,
+                               atol=1e-5)
+    np.testing.assert_array_equal(np.asarray(ids), np.asarray(ri))
+
+
 def test_filtered_topk_matches_prefbf():
     """Kernel vs the production jnp PreFBF path (cross-validation)."""
     from repro.core import prefbf
@@ -115,7 +139,7 @@ def test_gather_distance_sweep(n, d, b, m):
 @pytest.mark.parametrize("n,d,b,m0,m,nbits", [
     (500, 16, 4, 8, 8, 6),
     (900, 24, 6, 16, 8, 8),   # includes -1 pads below
-    (256, 8, 2, 32, 4, 5),
+    (256, 8, 2, 32, 8, 5),    # 5-bit codes; dsub = 1 keeps ADC ~ true d2
 ])
 def test_pq_adc_gather_sweep(n, d, b, m0, m, nbits):
     from repro.kernels.pq_adc import ops as pq_ops
