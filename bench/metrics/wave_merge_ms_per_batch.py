@@ -1,0 +1,8 @@
+"""Graph route: device time per batch of the waves' ``wave.merge`` stage: the
+two pool merges (concatenate, argsort, take); from the device trace, each
+operation given to the innermost scope of its name stack (``spans``)."""
+from spans import stage_ms_per_batch
+
+
+def read(ctx):
+    return stage_ms_per_batch(ctx, "wave.merge")

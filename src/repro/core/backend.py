@@ -44,9 +44,6 @@ from . import filters as F
 from . import prefbf, selector
 from .options import BuildSpec, SearchOptions
 from .search import favor_graph_search
-# gated host-side profiler scopes (nullcontext unless ObsSpec enables
-# kernel annotations); repro.obs.profiling imports nothing from core
-from ..obs.profiling import annotate as _annotate
 from ..index.delta import compose_topk_dev
 from ..index.epochs import ComponentEpochs
 from ..index.live import LiveState
@@ -218,9 +215,8 @@ class LocalBackend:
             D = exclusion.exclusion_distance(
                 jnp.asarray(p_hat), opts.ef, idx.delta_d, k=opts.k,
                 p_min=idx.sel_cfg.p_min, xp=jnp)
-            with _annotate("favor/local/graph_search"):
-                base = favor_graph_search(idx.g, queries, programs, D, cfg,
-                                          valid=valid)
+            base = favor_graph_search(idx.g, queries, programs, D, cfg,
+                                      valid=valid)
         else:
             b = int(queries.shape[0])
             base = {"ids": np.full((b, opts.k), -1, np.int64),
@@ -251,28 +247,25 @@ class LocalBackend:
             ids = np.full((b, opts.k), -1, np.int64)
             dists = np.full((b, opts.k), np.inf, np.float32)
         elif not opts.use_pq:
-            with _annotate("favor/local/prefbf_scan"):
-                ids, dists = prefbf.prefbf_topk(pv, pn, pi, pf, queries,
-                                                programs, k=opts.k,
-                                                chunk=idx.prefbf_chunk,
-                                                use_pallas=opts.use_pallas,
-                                                valid=valid)
+            ids, dists = prefbf.prefbf_topk(pv, pn, pi, pf, queries,
+                                            programs, k=opts.k,
+                                            chunk=idx.prefbf_chunk,
+                                            use_pallas=opts.use_pallas,
+                                            valid=valid)
         else:
             from ..quant import adc as quant_adc
             rr = opts.rerank if opts.rerank is not None else idx.rerank
             if idx.quantize == "pq":
-                with _annotate("favor/local/pq_adc_scan"):
-                    ids, dists = quant_adc.pq_prefbf_topk(
-                        idx._codes, pn, pi, pf, queries, programs,
-                        idx._cb_dev[0], pv, k=opts.k, rerank=rr,
-                        chunk=idx.prefbf_chunk, use_pallas=opts.use_pallas,
-                        valid=valid)
+                ids, dists = quant_adc.pq_prefbf_topk(
+                    idx._codes, pn, pi, pf, queries, programs,
+                    idx._cb_dev[0], pv, k=opts.k, rerank=rr,
+                    chunk=idx.prefbf_chunk, use_pallas=opts.use_pallas,
+                    valid=valid)
             else:
-                with _annotate("favor/local/sq_adc_scan"):
-                    ids, dists = quant_adc.sq_prefbf_topk(
-                        idx._codes, idx._cb_dev[0], idx._cb_dev[1], pn, pi,
-                        pf, queries, programs, pv, k=opts.k, rerank=rr,
-                        chunk=idx.prefbf_chunk, valid=valid)
+                ids, dists = quant_adc.sq_prefbf_topk(
+                    idx._codes, idx._cb_dev[0], idx._cb_dev[1], pn, pi,
+                    pf, queries, programs, pv, k=opts.k, rerank=rr,
+                    chunk=idx.prefbf_chunk, valid=valid)
         delta = self._delta()
         if delta is None:
             return ids, dists
@@ -769,9 +762,8 @@ class ShardedBackend:
         pad = queries.shape[0] - p_hat.shape[0]
         if pad:
             p_hat = jnp.concatenate([p_hat, jnp.repeat(p_hat[-1:], pad)])
-        with _annotate("favor/sharded/graph_search"):
-            ids, dists = self._fns(opts)["serve_graph_phat"](
-                self.db, queries, programs, p_hat, valid)
+        ids, dists = self._fns(opts)["serve_graph_phat"](
+            self.db, queries, programs, p_hat, valid)
         ids, dists = ids[:b], dists[:b]
         delta = self._delta()
         if delta is not None:
@@ -788,8 +780,7 @@ class ShardedBackend:
         queries, programs, valid, b = self._pad(queries, programs, valid)
         fn = "serve_brute_pq" if opts.use_pq else "serve_brute"
         fns = self._fns(opts, for_pq=opts.use_pq)
-        with _annotate(f"favor/sharded/{fn}"):
-            ids, dists = fns[fn](self.db, queries, programs, valid)
+        ids, dists = fns[fn](self.db, queries, programs, valid)
         ids, dists = ids[:b], dists[:b]
         delta = self._delta()
         if delta is not None:

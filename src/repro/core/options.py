@@ -275,11 +275,19 @@ class ObsSpec:
     0.0: they cost real work and are bench/diagnostic knobs, not
     steady-state ones.
 
-    ``kernel_annotations`` wraps backend dispatches in host-side
-    ``jax.profiler.TraceAnnotation`` scopes named by route and bucket, so
-    a ``jax.profiler`` capture attributes device time to kernels by route
-    (the jitted kernels themselves carry always-on ``jax.named_scope``
-    HLO metadata, which costs nothing at runtime).
+    ``kernel_annotations`` puts the program's spans on the profiler's
+    clock: every span of a traced batch (``estimate/wait``,
+    ``graph/search``, ``fetch``, ...) also opens a
+    ``jax.profiler.TraceAnnotation`` named ``favor.<path>`` and tagged with
+    the batch's trace id, and the front end marks its dispatch and settle
+    the same way -- so a ``jax.profiler`` capture attributes each stretch
+    of device idle time to what the host was doing, and one id follows a
+    batch across threads.  It rides on the traces, so it needs
+    ``trace_sample > 0``; with no capture running, an annotation costs a
+    few microseconds.  (The jitted code carries always-on
+    ``jax.named_scope`` HLO metadata -- the kernels' ``favor.<kernel>``
+    and the traversal's ``graph.*`` / ``wave.*`` stages -- which costs
+    nothing at runtime.)
 
     ``latency_buckets`` are the shared histogram upper bounds (seconds)
     for request latency and per-stage timings.

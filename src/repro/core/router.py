@@ -99,24 +99,27 @@ class PendingExecution:
             return self._result
         ids, dists, miss = self.ids, self.dists, self.miss
         gi, bi = self.gi, self.bi
-        if self.graph_out is not None:
-            out = self.graph_out
-            ids[miss[gi]] = np.asarray(out["ids"])[:len(gi)]
-            dists[miss[gi]] = np.asarray(out["dists"])[:len(gi)]
-            if "hops" in out:
-                self.hops[miss[gi]] = np.asarray(out["hops"])[:len(gi)]
-                self.path_td[miss[gi]] = np.asarray(
-                    out["path_td"])[:len(gi)]
-            else:
-                self.graph_diag = False
-            if "waves" in out:
-                self.waves[miss[gi]] = np.asarray(out["waves"])[:len(gi)]
-            else:
-                self.waves_diag = False
-        if self.brute_out is not None:
-            bid, bd = self.brute_out
-            ids[miss[bi]] = np.asarray(bid)[:len(bi)]
-            dists[miss[bi]] = np.asarray(bd)[:len(bi)]
+        with (self.tr.span("fetch") if self.tr is not None
+              else nullcontext()):
+            if self.graph_out is not None:
+                out = self.graph_out
+                ids[miss[gi]] = np.asarray(out["ids"])[:len(gi)]
+                dists[miss[gi]] = np.asarray(out["dists"])[:len(gi)]
+                if "hops" in out:
+                    self.hops[miss[gi]] = np.asarray(out["hops"])[:len(gi)]
+                    self.path_td[miss[gi]] = np.asarray(
+                        out["path_td"])[:len(gi)]
+                else:
+                    self.graph_diag = False
+                if "waves" in out:
+                    self.waves[miss[gi]] = np.asarray(
+                        out["waves"])[:len(gi)]
+                else:
+                    self.waves_diag = False
+            if self.brute_out is not None:
+                bid, bd = self.brute_out
+                ids[miss[bi]] = np.asarray(bid)[:len(bi)]
+                dists[miss[bi]] = np.asarray(bd)[:len(bi)]
         # the np.asarray conversions above synced the in-flight device work
         elapsed = time.perf_counter() - self.t0
         with (hook_lock if hook_lock is not None else nullcontext()):
@@ -245,9 +248,11 @@ def execute(backend, queries, filters, opts: SearchOptions, *,
 
     ``obs`` is an optional ``repro.obs.Obs``: when its tracer samples this
     batch, every pipeline stage below runs inside a span (wall time, route,
-    bucket shape, pad fraction, cache hits), and -- when the spec enables
-    kernel annotations -- the route dispatches run inside host-side
-    ``jax.profiler.TraceAnnotation`` scopes named by route and bucket.
+    bucket shape, pad fraction, cache hits), each of which -- when the spec
+    enables annotations -- is also a ``jax.profiler.TraceAnnotation`` on
+    the profiler's clock.  The estimate splits into ``dispatch`` (pad,
+    enqueue) and ``wait`` (the host sync on its result); ``finish`` adds
+    ``fetch``, the device-to-host copies of the route outputs.
     Obs hooks only *observe*; results are bit-identical with obs absent,
     disabled, or sampled out.
 
@@ -268,7 +273,6 @@ def execute(backend, queries, filters, opts: SearchOptions, *,
             return nullcontext()
     else:
         _span = tr.span
-    _ann = obs.annotate if obs is not None else (lambda name: nullcontext())
 
     with _span("compile", rows=b):
         programs = compile_programs(filters, backend.schema, b)
@@ -318,17 +322,20 @@ def execute(backend, queries, filters, opts: SearchOptions, *,
         mq = queries if full else queries[miss]
         mprogs = programs if full else take_programs(programs, miss)
         with _span("estimate", rows=len(miss)) as sp:
-            if spec is None:
-                batching.record(registry, "estimate", len(miss), len(miss))
-                mp_hat = np.asarray(backend.estimate(mprogs))
-            else:
-                eprogs, evalid = batching.pad_programs(spec, mprogs)
-                batching.record(registry, "estimate", len(evalid), len(miss))
-                if sp is not None:
-                    sp.attrs["bucket"] = int(len(evalid))
-                with _ann(f"favor/estimate/b{len(evalid)}"):
-                    mp_hat = np.asarray(backend.estimate(
-                        eprogs, valid=evalid))[:len(miss)]
+            with _span("dispatch"):
+                if spec is None:
+                    batching.record(registry, "estimate", len(miss),
+                                    len(miss))
+                    est = backend.estimate(mprogs)
+                else:
+                    eprogs, evalid = batching.pad_programs(spec, mprogs)
+                    batching.record(registry, "estimate", len(evalid),
+                                    len(miss))
+                    if sp is not None:
+                        sp.attrs["bucket"] = int(len(evalid))
+                    est = backend.estimate(eprogs, valid=evalid)
+            with _span("wait"):
+                mp_hat = np.asarray(est)[:len(miss)]
         with _span("route") as sp:
             plan = plan_routes(mp_hat, backend.sel_cfg.lam, opts.force)
             if sp is not None:
@@ -356,7 +363,7 @@ def execute(backend, queries, filters, opts: SearchOptions, *,
                     gspan.attrs["bucket"] = bucket
                     gspan.attrs["pad_frac"] = 1.0 - len(gi) / bucket
                 batching.record(registry, "graph", bucket, len(gi), opts)
-                with _span("search"), _ann(f"favor/graph/b{bucket}"):
+                with _span("search"):
                     pend.graph_out = backend.search_graph(
                         gq, gprogs, jnp.asarray(gp), opts, valid=gvalid)
         if len(bi):
@@ -374,7 +381,7 @@ def execute(backend, queries, filters, opts: SearchOptions, *,
                     bspan.attrs["bucket"] = bucket
                     bspan.attrs["pad_frac"] = 1.0 - len(bi) / bucket
                 batching.record(registry, "brute", bucket, len(bi), opts)
-                with _span("search"), _ann(f"favor/brute/b{bucket}"):
+                with _span("search"):
                     pend.brute_out = backend.search_brute(bq, bprogs, opts,
                                                           valid=bvalid)
 

@@ -312,32 +312,40 @@ def _graph_traverse(g: dict, queries: jnp.ndarray, programs: dict,
     # exact pre-live program and stay bit-identical
     alive = g.get("alive")
 
-    sstate = scorer.prepare(g, queries, programs)
-    ep = _descend(g, queries, scorer, sstate)        # (B,)
+    # Device scopes at the traversal's seams (HLO op metadata, trace-time
+    # only; results are unchanged): ``graph.init`` (descent, pools, visited
+    # set), per wave ``wave.select`` / ``wave.visit`` / ``wave.score`` /
+    # ``wave.filter`` / ``wave.merge``, and ``graph.compact`` (the lane
+    # ladder's gathers and scatters).  A device trace attributes the
+    # traversal's time to them; none contains ``favor.``, which names the
+    # Pallas kernels, each of which stays the innermost scope of its call.
+    with jax.named_scope("graph.init"):
+        sstate = scorer.prepare(g, queries, programs)
+        ep = _descend(g, queries, scorer, sstate)        # (B,)
 
-    # --- init pools with the entry point -----------------------------------
-    ep_d = scorer.score_block(g, sstate, ep[:, None])[:, 0]
-    ep_td = F.eval_program_gathered(
-        programs, g["attrs_int"][ep][:, None, :],
-        g["attrs_float"][ep][:, None, :], xp=jnp)[:, 0]
-    if alive is not None:
-        ep_td = ep_td & alive[ep]
-    ep_key = exclusion_compose(ep_d, ep_td, D)       # rsf: D = 0 -> plain d
-    seed_ok = ep_td if rsf else jnp.ones((B,), bool)
+        # --- init pools with the entry point -------------------------------
+        ep_d = scorer.score_block(g, sstate, ep[:, None])[:, 0]
+        ep_td = F.eval_program_gathered(
+            programs, g["attrs_int"][ep][:, None, :],
+            g["attrs_float"][ep][:, None, :], xp=jnp)[:, 0]
+        if alive is not None:
+            ep_td = ep_td & alive[ep]
+        ep_key = exclusion_compose(ep_d, ep_td, D)   # rsf: D = 0 -> plain d
+        seed_ok = ep_td if rsf else jnp.ones((B,), bool)
 
-    cand_d = jnp.full((B, ccap), INF).at[:, 0].set(ep_key)
-    cand_i = jnp.full((B, ccap), -1, jnp.int32).at[:, 0].set(ep)
-    res_d = jnp.full((B, ef), INF).at[:, 0].set(
-        jnp.where(seed_ok, ep_key, INF))
-    res_i = jnp.full((B, ef), -1, jnp.int32).at[:, 0].set(
-        jnp.where(seed_ok, ep, -1))
-    res_t = jnp.zeros((B, ef), bool).at[:, 0].set(ep_td)
-    visited = jnp.zeros((B, _visited_words(N)), jnp.uint32).at[
-        rows, ep >> 5].add(jnp.uint32(1) << (ep & 31).astype(jnp.uint32))
-    active = (jnp.ones((B,), bool) if valid is None
-              else jnp.asarray(valid, bool))
-    hops = jnp.zeros((B,), jnp.int32)
-    path_td = jnp.zeros((B,), jnp.int32)
+        cand_d = jnp.full((B, ccap), INF).at[:, 0].set(ep_key)
+        cand_i = jnp.full((B, ccap), -1, jnp.int32).at[:, 0].set(ep)
+        res_d = jnp.full((B, ef), INF).at[:, 0].set(
+            jnp.where(seed_ok, ep_key, INF))
+        res_i = jnp.full((B, ef), -1, jnp.int32).at[:, 0].set(
+            jnp.where(seed_ok, ep, -1))
+        res_t = jnp.zeros((B, ef), bool).at[:, 0].set(ep_td)
+        visited = jnp.zeros((B, _visited_words(N)), jnp.uint32).at[
+            rows, ep >> 5].add(jnp.uint32(1) << (ep & 31).astype(jnp.uint32))
+        active = (jnp.ones((B,), bool) if valid is None
+                  else jnp.asarray(valid, bool))
+        hops = jnp.zeros((B,), jnp.int32)
+        path_td = jnp.zeros((B,), jnp.int32)
 
     def stage_loop(state, programs, D, sstate, limit: int):
         """One while_loop over the (possibly compacted) lane set.
@@ -363,43 +371,45 @@ def _graph_traverse(g: dict, queries: jnp.ndarray, programs: dict,
             res_d, res_i, res_t = s["res_d"], s["res_i"], s["res_t"]
             active = s["active"]
 
-            # -- extract argmin of C (Algorithm 3 line 6) --------------------
-            j = jnp.argmin(cand_d, axis=1)
-            da = cand_d[rows, j]
-            va = cand_i[rows, j]
-            cand_d = jnp.where(active[:, None],
-                               cand_d.at[rows, j].set(INF), cand_d)
+            with jax.named_scope("wave.select"):
+                # -- extract argmin of C (Algorithm 3 line 6) ----------------
+                j = jnp.argmin(cand_d, axis=1)
+                da = cand_d[rows, j]
+                va = cand_i[rows, j]
+                cand_d = jnp.where(active[:, None],
+                                   cand_d.at[rows, j].set(INF), cand_d)
 
-            # -- termination (line 8, with section 5.4 guard) ----------------
-            worst = jnp.max(res_d, axis=1)           # +inf while R not full
-            full = jnp.isfinite(worst)
-            plain_term = (da > cfg.gamma * worst) & full
-            if rsf:
-                guard_ok = jnp.ones((S,), bool)
-            else:
-                n_valid = jnp.sum(jnp.isfinite(res_d), axis=1)
-                n_td = jnp.sum(res_t & jnp.isfinite(res_d), axis=1)
-                pbar = n_td / jnp.maximum(n_valid, 1)
-                guard_ok = (cfg.pbar_min <= 0.0) | (pbar > cfg.pbar_min)
-            terminate = plain_term & guard_ok
-            exhausted = ~jnp.isfinite(da)
-            new_active = active & ~terminate & ~exhausted
-            expand = new_active                      # lanes that expand v_a
+                # -- termination (line 8, with section 5.4 guard) ------------
+                worst = jnp.max(res_d, axis=1)       # +inf while R not full
+                full = jnp.isfinite(worst)
+                plain_term = (da > cfg.gamma * worst) & full
+                if rsf:
+                    guard_ok = jnp.ones((S,), bool)
+                else:
+                    n_valid = jnp.sum(jnp.isfinite(res_d), axis=1)
+                    n_td = jnp.sum(res_t & jnp.isfinite(res_d), axis=1)
+                    pbar = n_td / jnp.maximum(n_valid, 1)
+                    guard_ok = (cfg.pbar_min <= 0.0) | (pbar > cfg.pbar_min)
+                terminate = plain_term & guard_ok
+                exhausted = ~jnp.isfinite(da)
+                new_active = active & ~terminate & ~exhausted
+                expand = new_active                  # lanes that expand v_a
 
-            # -- gather + score the neighbor block ---------------------------
-            va_safe = jnp.maximum(va, 0)
-            nbrs = jnp.where(expand[:, None], g["neighbors0"][va_safe], -1)  # (S, M0)
-            ok = nbrs >= 0
-            safe = jnp.maximum(nbrs, 0)
-            seen = _seen_bits(s["visited"], rows, safe)
-            new = ok & ~seen
-            visited = _visit_bits(s["visited"], rows, safe, new)
+            with jax.named_scope("wave.visit"):
+                # -- gather the neighbor block, mark it visited --------------
+                va_safe = jnp.maximum(va, 0)
+                nbrs = jnp.where(expand[:, None], g["neighbors0"][va_safe],
+                                 -1)                 # (S, M0)
+                ok = nbrs >= 0
+                safe = jnp.maximum(nbrs, 0)
+                seen = _seen_bits(s["visited"], rows, safe)
+                new = ok & ~seen
+                visited = _visit_bits(s["visited"], rows, safe, new)
 
-            # profiling scope: stamps the per-wave gather+score+filter ops
-            # into HLO metadata so device traces attribute traversal time to
-            # waves (trace-time only; see repro.obs.profiling)
-            with jax.named_scope("favor.graph_wave"):
+            with jax.named_scope("wave.score"):
                 d = scorer.score_block(g, sstate, safe)
+
+            with jax.named_scope("wave.filter"):
                 td = F.eval_program_gathered(
                     programs, g["attrs_int"][safe], g["attrs_float"][safe],
                     xp=jnp)
@@ -407,24 +417,27 @@ def _graph_traverse(g: dict, queries: jnp.ndarray, programs: dict,
                     td = td & alive[safe]
                 key = exclusion_compose(d, td, D[:, None])   # Eq. 2
 
-            # -- pool insertion (lines 15-24) --------------------------------
-            worst_now = jnp.max(res_d, axis=1)       # +inf when R not full
-            eligible = new & (key < worst_now[:, None])
-            res_ok = (eligible & td) if rsf else eligible
-            res_d, res_i, res_t = _merge_pool(
-                res_d, res_i, res_t,
-                jnp.where(res_ok, key, INF), jnp.where(res_ok, nbrs, -1),
-                td & res_ok, ef)
-            cand_d, cand_i, _ = _merge_pool(
-                cand_d, cand_i, jnp.zeros_like(cand_i, bool),
-                jnp.where(eligible, key, INF), jnp.where(eligible, nbrs, -1),
-                jnp.zeros_like(nbrs, bool), ccap)
+            with jax.named_scope("wave.merge"):
+                # -- pool insertion (lines 15-24) ----------------------------
+                worst_now = jnp.max(res_d, axis=1)   # +inf when R not full
+                eligible = new & (key < worst_now[:, None])
+                res_ok = (eligible & td) if rsf else eligible
+                res_d, res_i, res_t = _merge_pool(
+                    res_d, res_i, res_t,
+                    jnp.where(res_ok, key, INF), jnp.where(res_ok, nbrs, -1),
+                    td & res_ok, ef)
+                cand_d, cand_i, _ = _merge_pool(
+                    cand_d, cand_i, jnp.zeros_like(cand_i, bool),
+                    jnp.where(eligible, key, INF),
+                    jnp.where(eligible, nbrs, -1),
+                    jnp.zeros_like(nbrs, bool), ccap)
 
-            va_td = F.eval_program_gathered(
-                programs, g["attrs_int"][va_safe][:, None, :],
-                g["attrs_float"][va_safe][:, None, :], xp=jnp)[:, 0]
-            if alive is not None:
-                va_td = va_td & alive[va_safe]
+            with jax.named_scope("wave.filter"):
+                va_td = F.eval_program_gathered(
+                    programs, g["attrs_int"][va_safe][:, None, :],
+                    g["attrs_float"][va_safe][:, None, :], xp=jnp)[:, 0]
+                if alive is not None:
+                    va_td = va_td & alive[va_safe]
             return {
                 "cand_d": cand_d, "cand_i": cand_i,
                 "res_d": res_d, "res_i": res_i, "res_t": res_t,
@@ -458,13 +471,13 @@ def _graph_traverse(g: dict, queries: jnp.ndarray, programs: dict,
     final = {k: state[k] for k in out_keys}
     perm = jnp.arange(B)
     progs_s, D_s, sstate_s = programs, D, sstate
-    with jax.named_scope("favor.graph_traverse"):
-        for si, S in enumerate(sizes):
-            limit = sizes[si + 1] if si + 1 < len(sizes) else 0
-            state = stage_loop(state, progs_s, D_s, sstate_s, limit)
-            if len(sizes) == 1:
-                final = {k: state[k] for k in out_keys}
-                break
+    for si, S in enumerate(sizes):
+        limit = sizes[si + 1] if si + 1 < len(sizes) else 0
+        state = stage_loop(state, progs_s, D_s, sstate_s, limit)
+        if len(sizes) == 1:
+            final = {k: state[k] for k in out_keys}
+            break
+        with jax.named_scope("graph.compact"):
             final = {k: final[k].at[perm].set(state[k]) for k in out_keys}
             if si + 1 < len(sizes):
                 nxt = sizes[si + 1]

@@ -98,8 +98,8 @@ def filtered_topk(vectors, norms, ints, floats, queries, programs, *,
         dvec = jnp.zeros((b,), jnp.float32)
     dvec_p = _pad_rows(dvec.astype(jnp.float32), b_pad, 0).reshape(b_pad, 1)
 
-    # HLO-metadata profiling scope (see repro.obs.profiling): trace-time
-    # only, zero runtime cost
+    # HLO-metadata profiling scope, the innermost around the pallas_call:
+    # the device trace names the kernel by it (trace-time only)
     with jax.named_scope("favor.filtered_topk"):
         out_d, out_i = filtered_topk_pallas(
             queries_p, vectors, norms, ints_t, floats_t, programs_p, dvec_p,

@@ -32,8 +32,8 @@ def gather_distance(vectors, norms, ints, floats, queries, nbr_ids, programs,
     # gathered attribute planes, (mi, B*M) / (mf, B*M)
     ints_g, floats_g = attr_planes(ints[safe.reshape(-1)],
                                    floats[safe.reshape(-1)], b_pad * m)
-    # HLO-metadata profiling scope (see repro.obs.profiling): trace-time
-    # only, zero runtime cost
+    # HLO-metadata profiling scope, the innermost around the pallas_call:
+    # the device trace names the kernel by it (trace-time only)
     with jax.named_scope("favor.gather_distance"):
         out_d, out_td = gather_distance_pallas(
             ids, _pad_rows(queries, b_pad, 0),

@@ -39,7 +39,7 @@ def pq_adc_gather(codes, luts, nbr_ids, *, block_q: int = 8,
         interpret = default_interpret()
     # named_scope stamps the kernel into HLO op metadata at trace time, so
     # a jax.profiler capture attributes its device time by name -- compiled
-    # executables carry it for free (repro.obs.profiling)
+    # executables carry it for free
     with jax.named_scope("favor.pq_adc_gather"):
         bq = _round_up(min(block_q, b), SUBLANES)
         b_pad = _round_up(b, bq)

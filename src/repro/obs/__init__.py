@@ -11,9 +11,15 @@ provides behind a single ``ObsSpec`` (``core.options``):
   tracer     -- per-request route traces through ``router.execute`` with a
                 slow-query ring (``trace.py``).
   probes     -- estimator-accuracy + route-confusion probes (``probes.py``).
-  profiling  -- gated ``jax.profiler.TraceAnnotation`` dispatch scopes
-                (``profiling.py``); jitted kernels carry always-on
-                ``jax.named_scope`` metadata independently.
+  annotations -- with ``ObsSpec.kernel_annotations`` on, every span opens
+                a ``jax.profiler.TraceAnnotation`` (``trace.annotation``),
+                the one path by which host spans reach the profiler; jitted
+                code carries ``jax.named_scope`` metadata independently.
+
+Every registry also counts XLA compiles by function
+(``favor_xla_compiles_total{fun}``), fed by one process-wide listener on
+JAX's compile event: a compile in steady state names the function that
+recompiled.
 
 ``ObsSpec(enabled=False)`` degrades every per-request hook to a no-op while
 keeping the registry live (stats still work); results are bit-identical
@@ -21,17 +27,50 @@ either way -- the obs layer observes, it never steers.
 """
 from __future__ import annotations
 
+import threading
 import time
+import weakref
 from contextlib import nullcontext
 
-from . import profiling
 from .probes import EstimatorProbe, RouteConfusion
 from .registry import Counter, Gauge, Histogram, MetricsRegistry
-from .trace import RequestTrace, SlowQuery, Span, Tracer
+from .trace import RequestTrace, SlowQuery, Span, Tracer, annotation
 
 __all__ = ["Counter", "EstimatorProbe", "Gauge", "Histogram",
            "MetricsRegistry", "Obs", "RequestTrace", "RouteConfusion",
-           "SlowQuery", "Span", "Tracer", "profiling"]
+           "SlowQuery", "Span", "Tracer"]
+
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+
+# the compile counters of every live registry; JAX's monitoring listeners
+# are process-wide, so one listener feeds them all
+_compile_counters: weakref.WeakSet = weakref.WeakSet()
+_compile_listener_lock = threading.Lock()
+_compile_listener_on = False
+
+
+def _on_compile(event: str, duration: float, **kw) -> None:
+    if event == COMPILE_EVENT:
+        fun = kw.get("fun_name", "unknown")
+        for c in list(_compile_counters):
+            c.inc(fun=fun)
+
+
+def count_compiles(registry: MetricsRegistry) -> Counter:
+    """``favor_xla_compiles_total{fun}`` on ``registry``, fed from then on
+    by every XLA compile in the process (or fetch from JAX's persistent
+    cache: both pass through the backend compile)."""
+    global _compile_listener_on
+    c = registry.counter("favor_xla_compiles_total",
+                         "XLA compiles (or persistent-cache loads), by "
+                         "jitted function", labels=("fun",))
+    with _compile_listener_lock:
+        _compile_counters.add(c)
+        if not _compile_listener_on:
+            from jax import monitoring
+            monitoring.register_event_duration_secs_listener(_on_compile)
+            _compile_listener_on = True
+    return c
 
 
 class Obs:
@@ -53,7 +92,8 @@ class Obs:
         self.spec = spec
         self.time_fn = time_fn
         self.registry = registry if registry is not None else MetricsRegistry()
-        self.tracer = (Tracer(spec, self.registry, time_fn)
+        self._annotate = spec.enabled and spec.kernel_annotations
+        self.tracer = (Tracer(spec, self.registry, time_fn, self._annotate)
                        if spec.enabled and spec.trace_sample > 0 else None)
         self.estimator_probe = (EstimatorProbe(spec, self.registry)
                                 if spec.enabled and spec.probe_sample > 0
@@ -61,9 +101,7 @@ class Obs:
         self.route_confusion = (RouteConfusion(spec, self.registry, time_fn)
                                 if spec.enabled and spec.shadow_sample > 0
                                 else None)
-        if spec.enabled and spec.kernel_annotations:
-            profiling.set_kernel_annotations(True)
-        self._annotate = spec.enabled and spec.kernel_annotations
+        count_compiles(self.registry)
         self.registry.on_reset(self._reset_components)
 
     @property
@@ -80,13 +118,13 @@ class Obs:
         if self.tracer is not None:
             self.tracer.finish(tr, **kw)
 
-    # -- kernel dispatch annotation -------------------------------------------
-    def annotate(self, name: str):
-        """Host-side TraceAnnotation context (nullcontext unless the spec
-        enables kernel annotations)."""
+    def annotation(self, path: str, trace_id: int | None = None):
+        """The profiler annotation ``favor.<path>`` for host work outside a
+        batch's span tree (the front end's dispatch and settle), gated like
+        every span's: a nullcontext unless the spec enables annotations."""
         if not self._annotate:
             return nullcontext()
-        return profiling.annotate(name)
+        return annotation(path, trace_id)
 
     # -- probes ---------------------------------------------------------------
     @property

@@ -24,6 +24,7 @@ import threading
 import time
 import warnings
 from collections import deque
+from contextlib import contextmanager
 from dataclasses import dataclass, field
 
 import numpy as np
@@ -75,6 +76,12 @@ class _InflightStep:
     pending: "router.PendingExecution"
     queries: np.ndarray
     flts: list
+
+    @property
+    def trace_id(self) -> int | None:
+        """The batch's obs trace id (None when sampled out or obs off)."""
+        tr = self.pending.tr
+        return tr.trace_id if tr is not None else None
 
 
 def _bucket(n: int, spec: BatchSpec | None = None) -> int:
@@ -209,6 +216,12 @@ class ServeEngine:
             "favor_inflight_steps",
             "Engine steps dispatched to the device but not yet finished "
             "(pipelined serving depth)")
+        self._m_lock_wait = reg.histogram(
+            "favor_engine_lock_wait_seconds",
+            "Time spent waiting for the engine lock, by site: serve (the "
+            "front end's submit + host phase), finish (per-request "
+            "accounting), hook (the finishing step's cache/obs hooks)",
+            labels=("site",), buckets=obs.spec.latency_buckets)
         self._last_step_end = 0.0   # perf_counter of last finish_batch
         self._m_merge_active = reg.gauge(
             "favor_merge_active",
@@ -244,6 +257,16 @@ class ServeEngine:
         if merge_background:
             from .merge import MergeController
             self._merge_ctl = MergeController(self)
+
+    @contextmanager
+    def locked(self, site: str):
+        """Hold the engine lock, timing the wait for it into
+        ``favor_engine_lock_wait_seconds{site}``: a long wait means the
+        serialized host phases, not the device, set the pace."""
+        t0 = time.perf_counter()
+        with self._lock:
+            self._m_lock_wait.observe(time.perf_counter() - t0, site=site)
+            yield
 
     def close(self) -> None:
         """Stop the background merge worker (if any).  Idempotent; the
@@ -482,14 +505,14 @@ class ServeEngine:
         try:
             # mutating finish hooks (cache record, obs trace) take the
             # engine lock; the device sync itself runs outside it
-            res = step.pending.finish(hook_lock=self._lock)
+            res = step.pending.finish(hook_lock=self.locked("hook"))
         finally:
             self._m_inflight.add(-1.0)
             # lets the merge controller tell "between steps" from "no
             # traffic" when pacing its build waves
             self._last_step_end = time.perf_counter()
         batch = step.batch
-        with self._lock:
+        with self.locked("finish"):
             t_done = self._time()
             if res.hops is None:
                 self._diag_known = False

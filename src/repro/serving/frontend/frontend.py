@@ -112,6 +112,10 @@ class FrontEnd:
         reg = engine.obs.registry
         reg.register_view("frontend", self._ledger_view)
         reg.on_reset(self._reset_ledgers)
+        self._m_queue = reg.histogram(
+            "favor_frontend_queue_seconds",
+            "Time a request waited in its tenant queue, from submit to "
+            "dispatch", buckets=engine.obs.spec.latency_buckets)
 
     # -- tenant bookkeeping ---------------------------------------------------
     def _scope_for(self, name: str) -> int:
@@ -228,9 +232,10 @@ class FrontEnd:
         """Runs in an executor slot: submit + host-phase dispatch under the
         engine lock (atomic, so a concurrent slot can never steal this
         batch's rows), then block on the device work with no lock held.
-        Returns (pending, engine Response) pairs."""
+        Returns one (trace id, [(pending, engine Response)]) per engine
+        step."""
         eng = self.engine
-        with eng._lock:
+        with eng.obs.annotation("frontend/dispatch"), eng.locked("serve"):
             by_rid = {}
             for p in batch:
                 rid = eng.submit(p.query, p.flt,
@@ -242,22 +247,24 @@ class FrontEnd:
                 if s is None:
                     break
                 steps.append(s)
-        out = []
-        for s in steps:
-            out.extend(eng.finish_batch(s))
-        return [(by_rid[r.rid], r) for r in out if r.rid in by_rid]
+        return [(s.trace_id, [(by_rid[r.rid], r)
+                              for r in eng.finish_batch(s)
+                              if r.rid in by_rid])
+                for s in steps]
 
-    def _settle(self, pairs) -> None:
-        """Resolve one completed step's futures (loop thread only)."""
+    def _settle(self, steps) -> None:
+        """Resolve one completed dispatch's futures (loop thread only)."""
         now = self._clock()
-        for p, r in pairs:
-            st = self._tenants[p.tenant]
-            st.served += 1
-            lat = now - p.t_submit
-            st.latencies.append(lat)
-            if not p.future.done():
-                p.future.set_result(Response(
-                    r.rid, r.ids, r.dists, r.route, r.p_hat, lat))
+        for trace_id, pairs in steps:
+            with self.engine.obs.annotation("frontend/settle", trace_id):
+                for p, r in pairs:
+                    st = self._tenants[p.tenant]
+                    st.served += 1
+                    lat = now - p.t_submit
+                    st.latencies.append(lat)
+                    if not p.future.done():
+                        p.future.set_result(Response(
+                            r.rid, r.ids, r.dists, r.route, r.p_hat, lat))
 
     async def _run(self) -> None:
         loop = asyncio.get_running_loop()
@@ -298,6 +305,9 @@ class FrontEnd:
             batch = self._dequeue()
             if not batch:
                 continue
+            t_submit = np.fromiter((p.t_submit for p in batch), np.float64,
+                                   len(batch))
+            self._m_queue.observe_many(self._clock() - t_submit)
             self._dispatches += 1
             self._dispatched_rows += len(batch)
             fut = loop.run_in_executor(self._exec, self._serve, batch)

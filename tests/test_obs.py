@@ -17,7 +17,10 @@ No pytest-asyncio: async scenarios run through ``asyncio.run``.
 """
 import asyncio
 import json
+import re
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -25,6 +28,7 @@ from repro.cache import CachingBackend
 from repro.core import (BatchSpec, CacheSpec, FrontEndSpec, LocalBackend,
                         ObsSpec, SearchOptions, router)
 from repro.core import filters as F
+from repro.core.search import favor_graph_search
 from repro.obs import MetricsRegistry, Obs, RequestTrace
 from repro.obs.probes import innermost, true_fraction
 from repro.obs.trace import sample_period
@@ -425,6 +429,9 @@ def test_frontend_coalesced_batch_traces(small_index, small_dataset):
     total = 0
     for tr in traces:
         names = [s.name for s in tr.spans]
+        # the slow-query log follows the pipeline only in a slow batch
+        if names[-1] == "slow_log":
+            names.pop()
         assert names[0] == "compile" and names[-1] == "cache_record", names
         for sp in tr.spans:     # spans nest: children close inside parents
             for c in sp.children:
@@ -501,3 +508,207 @@ def test_frontend_ledgers_in_exposition(small_index, small_dataset):
             in text)
     assert 'favor_view{view="cache",path="semantic.' in text
     assert snap["views"]["frontend"]["tenants"]["default"]["served"] == 4
+
+
+# ---------------------------------------------------------------------------
+# One timeline: span paths, annotations, lock waits, queue time, compiles
+# ---------------------------------------------------------------------------
+def _stage_series(eng) -> dict:
+    hist = eng.obs.registry.snapshot()["histograms"]["favor_stage_seconds"]
+    return {k[len('stage="'):-1]: v for k, v in hist["series"].items()}
+
+
+def test_child_spans_recorded_under_their_paths(small_index, small_dataset):
+    _, _, schema = small_dataset
+    eng = ServeEngine(LocalBackend(small_index), OPTS, max_batch=8,
+                      time_fn=FakeClock(tick=0.001),
+                      obs=ObsSpec(slow_ms=None))
+    _drive(eng, schema, n=16)
+    series = _stage_series(eng)
+    traces = list(eng.obs.tracer.traces)
+    assert len(traces) == 2
+    route = "graph" if "graph" in series else "brute"
+    for path in ("estimate/dispatch", "estimate/wait", f"{route}/pad",
+                 f"{route}/search", "fetch"):
+        assert series[path]["count"] == 2, path
+    # children never land under bare names: host_ms_per_batch subtracts a
+    # bare "search" stage, which must stay unrecorded
+    assert "search" not in series and "pad" not in series
+    assert "dispatch" not in series and "wait" not in series
+    # top-level stages sum exactly what the traces' top-level spans took
+    for name in ("compile", "cache_lookup", "estimate", "route", route,
+                 "fetch"):
+        want = sum(sp.duration_s for tr in traces for sp in tr.spans
+                   if sp.name == name)
+        assert series[name]["sum"] == pytest.approx(want), name
+    # every span of every trace fed the histogram exactly once
+    n_spans = sum(len(list(tr.walk())) for tr in traces)
+    assert sum(v["count"] for v in series.values()) == n_spans
+
+
+def test_estimate_wait_fetch_and_slow_log_spans(small_index, small_dataset):
+    _, _, schema = small_dataset
+    eng = ServeEngine(LocalBackend(small_index), OPTS, max_batch=8,
+                      obs=ObsSpec(slow_ms=0.0))
+    _drive(eng, schema, n=8)
+    tr = eng.obs.tracer.traces[-1]
+    paths = [sp.path for sp in tr.walk()]
+    for path in ("estimate/dispatch", "estimate/wait", "fetch", "slow_log"):
+        assert path in paths, paths
+    assert [sp.name for sp in tr.spans][-1] == "slow_log"
+    est = next(sp for sp in tr.spans if sp.name == "estimate")
+    assert [c.name for c in est.children] == ["dispatch", "wait"]
+    assert sum(c.duration_s for c in est.children) <= est.duration_s
+    # the slow entries carry the pipeline's stages, not the log's own span
+    sq = eng.obs.tracer.slow_log[0]
+    assert "fetch" in sq.stages_ms and "slow_log" not in sq.stages_ms
+    assert _stage_series(eng)["slow_log"]["count"] == 1
+
+
+def test_lock_wait_and_queue_histograms_count_batches_and_requests(
+        small_index, small_dataset):
+    _, _, schema = small_dataset
+
+    async def main():
+        eng = ServeEngine(LocalBackend(small_index), OPTS, max_batch=4)
+        fe = FrontEnd(eng, FrontEndSpec(coalesce_ms=5.0, coalesce_target=4,
+                                        parallel_steps=2))
+        qs = _queries(12, 16, seed=31)
+        await asyncio.gather(*[fe.submit(qs[i], _flt(schema))
+                               for i in range(12)])
+        st = fe.stats
+        snap = eng.obs.snapshot()
+        await fe.close()
+        return st, snap
+
+    st, snap = asyncio.run(main())
+    batches = st["engine"]["batches"]
+    assert batches >= 3
+    waits = snap["histograms"]["favor_engine_lock_wait_seconds"]["series"]
+    assert waits['site="serve"']["count"] == st["coalesce"]["dispatches"]
+    assert waits['site="finish"']["count"] == batches
+    assert waits['site="hook"']["count"] == batches
+    assert all(v["sum"] >= 0.0 for v in waits.values())
+    queue = snap["histograms"]["favor_frontend_queue_seconds"]["series"][""]
+    assert queue["count"] == 12
+    assert queue["sum"] >= 0.0
+
+
+class _Recorder:
+    """Stands in for ``jax.profiler.TraceAnnotation``: records each
+    annotation's name and metadata."""
+    seen: list = []
+
+    def __init__(self, name, **kw):
+        self.seen.append((name, kw))
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+
+@pytest.mark.parametrize("annotate", [True, False])
+def test_annotations_carry_the_batch_trace_id(small_index, small_dataset,
+                                              monkeypatch, annotate):
+    _, _, schema = small_dataset
+    monkeypatch.setattr(_Recorder, "seen", [])
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", _Recorder)
+
+    async def main():
+        eng = ServeEngine(LocalBackend(small_index), OPTS, max_batch=8,
+                          obs=ObsSpec(kernel_annotations=annotate))
+        fe = FrontEnd(eng, FrontEndSpec(coalesce_ms=5.0, coalesce_target=8))
+        qs = _queries(8, 16, seed=33)
+        await asyncio.gather(*[fe.submit(qs[i], _flt(schema))
+                               for i in range(8)])
+        traces = list(eng.obs.tracer.traces)
+        await fe.close()
+        return traces
+
+    traces = asyncio.run(main())
+    seen = _Recorder.seen
+    if not annotate:
+        assert seen == []
+        return
+    ids = {tr.trace_id for tr in traces}
+    names = [name for name, _ in seen]
+    assert all(name.startswith("favor.") for name in names)
+    for name in ("favor.compile", "favor.estimate/dispatch",
+                 "favor.estimate/wait", "favor.fetch",
+                 "favor.frontend/dispatch", "favor.frontend/settle"):
+        assert name in names, names
+    assert ("favor.graph/search" in names) or ("favor.brute/search" in names)
+    for name, kw in seen:
+        if name == "favor.frontend/dispatch":
+            assert kw == {}      # opened before the batch's trace exists
+        else:
+            assert kw["trace_id"] in ids, (name, kw)
+    # every span of every trace was annotated once, under its own trace id
+    spans = sorted((f"favor.{sp.path}", tr.trace_id)
+                   for tr in traces for sp in tr.walk())
+    annotated = sorted((name, kw["trace_id"]) for name, kw in seen
+                       if not name.startswith("favor.frontend/"))
+    assert annotated == spans
+
+
+def test_annotated_results_match_obs_off(small_index, small_dataset):
+    _, _, schema = small_dataset
+    on = ServeEngine(LocalBackend(small_index), OPTS, max_batch=8,
+                     obs=ObsSpec(kernel_annotations=True, slow_ms=0.0))
+    off = ServeEngine(LocalBackend(small_index), OPTS, max_batch=8,
+                      obs=ObsSpec(enabled=False))
+    for a, b in zip(_drive(on, schema, n=16, seed=35),
+                    _drive(off, schema, n=16, seed=35)):
+        assert np.array_equal(a.ids, b.ids)
+        assert np.array_equal(a.dists, b.dists)
+        assert a.route == b.route and a.p_hat == b.p_hat
+
+
+def test_xla_compiles_counted_by_function():
+    obs = Obs(ObsSpec())
+    counter = obs.registry.counter("favor_xla_compiles_total",
+                                   labels=("fun",))
+
+    @jax.jit
+    def favor_fresh_compile_probe(x):
+        return x * 3.0 + 1.0
+
+    favor_fresh_compile_probe(jnp.arange(5.0)).block_until_ready()
+    # JAX names the compiled function as it jits it: jit(<name>)
+    assert counter.value(fun="jit(favor_fresh_compile_probe)") >= 1
+    n = counter.total()
+    favor_fresh_compile_probe(jnp.arange(5.0)).block_until_ready()
+    assert counter.total() == n          # a cached executable: no compile
+    text = obs.prometheus_text()
+    assert ('favor_xla_compiles_total{fun="jit(favor_fresh_compile_probe)"}'
+            in text)
+
+
+WAVE_SCOPES = ("graph.init", "wave.select", "wave.visit", "wave.score",
+               "wave.filter", "wave.merge", "graph.compact")
+
+
+@pytest.mark.parametrize("use_pallas", [False, True])
+def test_traversal_hlo_carries_every_wave_scope(small_index, use_pallas):
+    opts = SearchOptions(k=5, ef=32, use_pallas=use_pallas)
+    b = 8
+    schema = small_index.attrs.schema
+    programs = router.compile_programs(_flt(schema), schema, b)
+    lowered = favor_graph_search.lower(
+        small_index.g, jnp.asarray(_queries(b, 16)), programs,
+        jnp.zeros((b,), jnp.float32), opts.search_config(),
+        valid=jnp.ones((b,), bool))
+    text = lowered.as_text(debug_info=True)
+    for scope in WAVE_SCOPES:
+        assert scope in text, scope
+        assert "favor." not in scope     # kernels alone carry favor.<name>
+    assert "favor.graph_wave" not in text
+    assert "favor.graph_traverse" not in text
+    if use_pallas:
+        # the kernel's own scope stays innermost around its pallas_call:
+        # in the compiled program's op metadata it nests inside wave.score
+        op_names = re.findall(r'op_name="([^"]*)"', lowered.compile().as_text())
+        assert any(re.search(r"/wave\.score/(jit\([^)/]*\)/)?"
+                             r"favor\.gather_distance/", n) for n in op_names)
