@@ -5,10 +5,12 @@ TPU-native realization of Algorithms 2 + 3 (DESIGN.md section 3):
  * the query batch runs as ONE ``lax.while_loop`` whose state carries a lane
    per query; finished lanes are masked, the loop ends when all lanes do;
  * the candidate set C and result set R are fixed-capacity distance-sorted
-   pools updated by merge-sort of (pool || new-neighbor-block) -- no dynamic
-   heaps.  C capacity = ``cand_cap`` (default ef) is the bounded-memory
-   approximation of the paper's unbounded heap; recall parity with the
-   refimpl oracle is asserted in tests and measured in benchmarks;
+   pools updated by a stable rank-and-select merge of (pool ||
+   new-neighbor-block) with no sort or gather (``_merge_pool``) -- no
+   dynamic heaps.  C capacity = ``cand_cap`` (default ef) is the
+   bounded-memory approximation of the paper's unbounded heap; recall
+   parity with the refimpl oracle is asserted in tests and measured in
+   benchmarks;
  * neighbor-block scoring is pluggable (``core.scoring``): the same
    traversal body runs full-precision f32 (ExactScorer), PQ asymmetric
    distances over gathered uint8 codes (PqAdcScorer: the ADC LUT is built
@@ -277,16 +279,37 @@ def _descend(g: dict, queries: jnp.ndarray, scorer, sstate: dict) -> jnp.ndarray
     return cur
 
 
-def _merge_pool(pool_d, pool_i, pool_t, new_d, new_i, new_t, cap: int):
-    """Merge (B, cap) pools with (B, M) new entries, keep best ``cap``.
-    Ineligible new entries must carry d=+inf."""
-    d = jnp.concatenate([pool_d, new_d], axis=1)
-    i = jnp.concatenate([pool_i, new_i], axis=1)
-    t = jnp.concatenate([pool_t, new_t], axis=1)
-    order = jnp.argsort(d, axis=1)[:, :cap]
-    return (jnp.take_along_axis(d, order, axis=1),
-            jnp.take_along_axis(i, order, axis=1),
-            jnp.take_along_axis(t, order, axis=1))
+def _merge_pool(pool: tuple, new: tuple, cap: int) -> tuple:
+    """Merge (B, cap) pools with (B, M) new entries, keep the best ``cap``.
+
+    ``pool`` and ``new`` are matching tuples of arrays, distances first,
+    then payloads (ids, flags); ineligible new entries must carry d=+inf.
+    The result is the stable ascending sort of ``pool || new`` by distance,
+    cut to ``cap`` -- what ``argsort`` + ``take_along_axis`` give -- built
+    with neither a sort nor a gather (both slow per row on TPU): an
+    element's rank is the count of elements that sort before it (smaller,
+    or equal and earlier), a (B, L, L) compare and sum, and output slot
+    ``p`` selects the one element of rank ``p``, a (B, L, cap) one-hot
+    select and sum.  Numbers pass the select as their int32 bits, so every
+    value comes out bit for bit, ``+inf`` payloads included.  Distances are
+    never NaN (a NaN would share a rank).
+    """
+    cols = [jnp.concatenate([p, n], axis=1) for p, n in zip(pool, new)]
+    d = cols[0]
+    pos = jnp.arange(d.shape[1])
+    dj, di = d[:, :, None], d[:, None, :]
+    before = (dj < di) | ((dj == di) & (pos[:, None] < pos[None, :]))
+    rank = jnp.sum(before, axis=1, dtype=jnp.int32)          # (B, L)
+    hit = rank[:, :, None] == jnp.arange(cap)                # (B, L, cap)
+
+    def select(x):
+        if x.dtype == jnp.bool_:
+            return jnp.any(hit & x[:, :, None], axis=1)
+        bits = jax.lax.bitcast_convert_type(x, jnp.int32)
+        out = jnp.sum(jnp.where(hit, bits[:, :, None], 0), axis=1)
+        return jax.lax.bitcast_convert_type(out, x.dtype)
+
+    return tuple(select(x) for x in cols)
 
 
 def _graph_traverse(g: dict, queries: jnp.ndarray, programs: dict,
@@ -423,14 +446,13 @@ def _graph_traverse(g: dict, queries: jnp.ndarray, programs: dict,
                 eligible = new & (key < worst_now[:, None])
                 res_ok = (eligible & td) if rsf else eligible
                 res_d, res_i, res_t = _merge_pool(
-                    res_d, res_i, res_t,
-                    jnp.where(res_ok, key, INF), jnp.where(res_ok, nbrs, -1),
-                    td & res_ok, ef)
-                cand_d, cand_i, _ = _merge_pool(
-                    cand_d, cand_i, jnp.zeros_like(cand_i, bool),
-                    jnp.where(eligible, key, INF),
-                    jnp.where(eligible, nbrs, -1),
-                    jnp.zeros_like(nbrs, bool), ccap)
+                    (res_d, res_i, res_t),
+                    (jnp.where(res_ok, key, INF), jnp.where(res_ok, nbrs, -1),
+                     td & res_ok), ef)
+                cand_d, cand_i = _merge_pool(
+                    (cand_d, cand_i),
+                    (jnp.where(eligible, key, INF),
+                     jnp.where(eligible, nbrs, -1)), ccap)
 
             with jax.named_scope("wave.filter"):
                 va_td = F.eval_program_gathered(

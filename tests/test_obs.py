@@ -712,3 +712,25 @@ def test_traversal_hlo_carries_every_wave_scope(small_index, use_pallas):
         op_names = re.findall(r'op_name="([^"]*)"', lowered.compile().as_text())
         assert any(re.search(r"/wave\.score/(jit\([^)/]*\)/)?"
                              r"favor\.gather_distance/", n) for n in op_names)
+
+
+def test_wave_merge_compiles_without_sort_or_gather(small_index):
+    """The pool merge is rank-and-select: no sort and no gather of the
+    compiled traversal carries the ``wave.merge`` scope, which is there."""
+    opts = SearchOptions(k=5, ef=32)
+    b = 8
+    schema = small_index.attrs.schema
+    programs = router.compile_programs(_flt(schema), schema, b)
+    text = favor_graph_search.lower(
+        small_index.g, jnp.asarray(_queries(b, 16)), programs,
+        jnp.zeros((b,), jnp.float32), opts.search_config(),
+        valid=jnp.ones((b,), bool)).compile().as_text()
+    merge_ops = set()
+    for line in text.splitlines():
+        name = re.search(r'op_name="([^"]*)"', line)
+        if name and "wave.merge/" in name.group(1):
+            # "%name = <shape> <opcode>(operands), ..., metadata={...}"
+            merge_ops.add(re.match(r"\s*(?:ROOT\s+)?\S+\s*=\s*.*?\s([\w-]+)\(",
+                                   line).group(1))
+    assert merge_ops, "no compiled op carries wave.merge"
+    assert not merge_ops & {"sort", "gather"}, merge_ops

@@ -1,14 +1,18 @@
 """JAX production search vs the numpy oracle + baselines + end-to-end API."""
 import numpy as np
+import jax
 import jax.numpy as jnp
 import pytest
 
+import repro.core.search as search_mod
 from repro.core import (FavorIndex, SearchConfig, compile_filter,
                         favor_graph_search, graph_arrays, paper_filters,
                         rsf_graph_search, stack_programs)
 from repro.core import exclusion
 from repro.core import filters as F
 from repro.core import refimpl
+from repro.core.scoring import scorer_for
+from repro.core.search import _merge_pool
 
 
 def _truth(vecs, mask, q, k):
@@ -146,3 +150,72 @@ def test_save_load_end2end(small_index, small_dataset, queries, tmp_path):
     r1 = small_index.search(queries[:4], flt, k=5, ef=48)
     r2 = fi2.search(queries[:4], flt, k=5, ef=48)
     np.testing.assert_array_equal(r1.ids, r2.ids)
+
+
+def _argsort_merge(pool, new, cap):
+    """The pool merge as a stable argsort and gathers: the oracle that
+    ``_merge_pool`` has to match bit for bit."""
+    cols = [jnp.concatenate([p, n], axis=1) for p, n in zip(pool, new)]
+    order = jnp.argsort(cols[0], axis=1)[:, :cap]
+    return tuple(jnp.take_along_axis(c, order, axis=1) for c in cols)
+
+
+@pytest.mark.parametrize("b,cap,m", [(1, 32, 16), (8, 128, 32), (4, 512, 32)])
+def test_merge_pool_matches_argsort_merge(b, cap, m):
+    rng = np.random.default_rng(cap + m)
+    # sorted pools of few distinct values (many ties) with +inf tails of
+    # random length (row 0 full), and an +inf hole where wave.select pops
+    # C's minimum; new blocks with ties and +inf runs (ineligible entries)
+    pool_d = np.sort(rng.integers(0, 8, (b, cap)), axis=1).astype(np.float32)
+    n_fin = rng.integers(cap // 2, cap + 1, b)
+    n_fin[0] = cap
+    pool_d[np.arange(cap)[None, :] >= n_fin[:, None]] = np.inf
+    pool_d[np.arange(b), rng.integers(0, cap, b)] = np.inf
+    new_d = rng.integers(0, 8, (b, m)).astype(np.float32)
+    new_d[rng.random((b, m)) < 0.3] = np.inf
+    pool = (pool_d, rng.integers(-1, 10**6, (b, cap), dtype=np.int32),
+            rng.random((b, cap)) < 0.5)
+    new = (new_d, rng.integers(-1, 10**6, (b, m), dtype=np.int32),
+           rng.random((b, m)) < 0.5)
+    merge = jax.jit(_merge_pool, static_argnums=2)
+    # R carries (dists, ids, TD flags); C carries (dists, ids)
+    for n_cols in (3, 2):
+        got = merge(pool[:n_cols], new[:n_cols], cap)
+        want = _argsort_merge(pool[:n_cols], new[:n_cols], cap)
+        assert len(got) == n_cols
+        for g, w in zip(got, want):
+            g, w = np.asarray(g), np.asarray(w)
+            assert g.dtype == w.dtype and g.shape == w.shape == (b, cap)
+            # bit for bit, ids and flags of +inf entries included
+            assert np.array_equal(g.view(np.uint8), w.view(np.uint8))
+    if b > 1:
+        assert np.isinf(np.asarray(want[0])).any()   # +inf payloads compared
+
+
+@pytest.mark.parametrize("rsf", [False, True])
+def test_traversal_matches_argsort_merge(small_index, small_dataset, queries,
+                                         monkeypatch, rsf):
+    """Same trajectories whichever merge the traversal runs: the served
+    entry points against the body traced anew with the argsort merge."""
+    flt, prog, mask = _setup(small_index, small_dataset, "equality_int")
+    n = len(queries)
+    progs = {kk: jnp.asarray(v) for kk, v in
+             stack_programs([prog] * n).items()}
+    cfg = SearchConfig(k=10, ef=48, use_pallas=False)
+    q = jnp.asarray(queries)
+    if rsf:
+        D = jnp.zeros((n,), jnp.float32)
+        got = rsf_graph_search(small_index.g, q, progs, cfg)
+    else:
+        D = jnp.full((n,), exclusion.exclusion_distance(
+            mask.mean(), cfg.ef, small_index.delta_d), jnp.float32)
+        got = favor_graph_search(small_index.g, q, progs, D, cfg)
+    monkeypatch.setattr(search_mod, "_merge_pool", _argsort_merge)
+    # a fresh jit traces the body again, so the oracle merge is in it
+    want = jax.jit(lambda g, q, p, D: search_mod._graph_traverse(
+        g, q, p, D, cfg, scorer_for(cfg), None, rsf=rsf))(
+            small_index.g, q, progs, D)
+    assert int(np.asarray(want["waves"])[0]) > 1
+    for key in ("ids", "dists", "hops", "path_td", "waves"):
+        np.testing.assert_array_equal(np.asarray(got[key]),
+                                      np.asarray(want[key]), err_msg=key)
