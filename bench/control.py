@@ -10,8 +10,11 @@ has to catch, at a cell's own size, for several seeds.
 one precision step down (``reference.control_answers``), over every item of
 the pool, on the host.  ``--fault ef_k``: the served path as the cell runs
 it, on the chip, with the traversal's search width cut to ``k`` -- a graph
-route that ignores ``ef``; one run of ``run.py`` per seed, in this process,
-with a window of ``--seconds``.
+route that ignores ``ef``.  ``--fault rerank_k``: its compressed-route
+counterpart, for a cell whose brute route scans PQ or SQ codes
+(``search.use_pq``): the served path with ``search.rerank = 0``, so that only
+the codes' own top ``k`` get exact distances.  Each served fault is one run
+of ``run.py`` per seed, in this process, with a window of ``--seconds``.
 
 Prints, per seed, the numbers ``correct`` compares and their limits.  A
 sound limit lies below the reading that each fault is there to raise.
@@ -44,18 +47,20 @@ def control_readings(cell, seed: int) -> tuple[dict, dict]:
     items = np.arange(pool.size)
     ids, dists = reference.control_answers(vecs, ints, floats, cols, pool, k,
                                            items)
-    # every control answer is a full scan: hold it to the brute route's rule
+    # every control answer is a full exact scan: hold it to that rule
     return reference.compare(items, ids, dists, np.ones(len(items), bool), 0,
-                             vecs, ints, floats, cols, pool, k)
+                             vecs, ints, floats, cols, pool, k,
+                             brute_exact=True)
 
 
-def ef_k_readings(cell, seed: int, seconds: float,
-                  expect_platform: str = "tpu",
-                  overrides: dict | None = None) -> tuple[dict, dict]:
+def served_readings(cell, seed: int, seconds: float, change: dict,
+                    expect_platform: str = "tpu",
+                    overrides: dict | None = None) -> tuple[dict, dict]:
+    """One run of the served path with ``change`` applied to the
+    configuration's ``search`` settings (after ``overrides``)."""
     import run
     ov = dict(overrides or {})
-    search = dict(ov.get("search", cell.config["search"]))
-    ov["search"] = dict(search, ef=search["k"])
+    ov["search"] = dict(ov.get("search", cell.config["search"]), **change)
     buf = io.StringIO()
     with contextlib.redirect_stdout(buf):
         rc = run.main(["--workload", cell.name, "--seed", str(seed),
@@ -70,19 +75,42 @@ def ef_k_readings(cell, seed: int, seconds: float,
     return numbers, {"recall": recall, "attempted": last["attempted"]}
 
 
+def ef_k_readings(cell, seed: int, seconds: float,
+                  expect_platform: str = "tpu",
+                  overrides: dict | None = None) -> tuple[dict, dict]:
+    search = (overrides or {}).get("search", cell.config["search"])
+    return served_readings(cell, seed, seconds, {"ef": search["k"]},
+                           expect_platform, overrides)
+
+
+def rerank_k_readings(cell, seed: int, seconds: float,
+                      expect_platform: str = "tpu",
+                      overrides: dict | None = None) -> tuple[dict, dict]:
+    ov = overrides or {}
+    if not (ov.get("search", cell.config["search"])["use_pq"]
+            and ov.get("quant", cell.config.get("quant"))):
+        raise SystemExit(f"--fault rerank_k: {cell.name} has no compressed "
+                         f"brute route (search.use_pq and quant)")
+    return served_readings(cell, seed, seconds, {"rerank": 0},
+                           expect_platform, overrides)
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--workload", required=True)
     ap.add_argument("--seeds", required=True)
-    ap.add_argument("--fault", choices=("control", "ef_k"), default="control")
+    ap.add_argument("--fault", choices=("control", "ef_k", "rerank_k"),
+                    default="control")
     ap.add_argument("--seconds", type=float, default=10.0)
     args = ap.parse_args(argv)
     cell = workload.load_cell(args.workload)
     for seed in (int(s) for s in args.seeds.split(",") if s):
         if args.fault == "control":
             numbers, diag = control_readings(cell, seed)
-        else:
+        elif args.fault == "ef_k":
             numbers, diag = ef_k_readings(cell, seed, args.seconds)
+        else:
+            numbers, diag = rerank_k_readings(cell, seed, args.seconds)
         print(json.dumps({"workload": cell.name, "fault": args.fault,
                           "seed": seed, "numbers": numbers,
                           "limits": cell.limits,

@@ -104,13 +104,16 @@ def per_batch_ms(ctx: Context, seconds: float | None) -> float | None:
 
 def host_ms_per_batch(ctx: Context) -> float | None:
     """Host time of the engine's step outside the selectivity estimate and
-    the device dispatch: filter compile, cache lookup, routing, sub-batch
-    slicing and padding (``favor_stage_seconds``)."""
+    the routes' device dispatch: filter compile, cache lookup, routing,
+    sub-batch slicing and padding (``favor_stage_seconds``).  Each route's
+    span less its ``search`` child (``graph/search``, ``brute/search``),
+    which enqueues that route's device work."""
     if ctx.batches == 0:
         return None
     host = sum(ctx.stage_s(s) for s in ("compile", "cache_lookup", "route",
                                         "graph", "brute"))
-    return per_batch_ms(ctx, host - ctx.stage_s("search"))
+    dispatch = ctx.stage_s("graph/search") + ctx.stage_s("brute/search")
+    return per_batch_ms(ctx, host - dispatch)
 
 
 def batch_fill(ctx: Context) -> float | None:

@@ -13,16 +13,22 @@ Comparison, over every answer the window produced (``compare``):
   of range or repeated, a row that fails the request's filter, distances
   not ascending, no id at all for a filter that passes at least
   ``GRAPH_SERVES`` of the rows, or -- on the brute route, which scans
-  every row and is exact -- fewer ids than ``min(k, rows that pass)`` or a
-  row farther than the reference's k-th (up to ``TIE`` of it: rows that
-  tie may come in either order).  Limit 0.  (The graph route is
-  approximate: a short answer counts against its recall, not here, and so
-  does an empty one for a filter under 1%, which only the selector's
-  sampled estimate sends to the graph.)
+  every row -- fewer ids than ``min(k, rows that pass)``.  Where the
+  configuration's brute route is exact (``brute_exact``: it scans the
+  float32 rows), a row farther than the reference's k-th (up to ``TIE`` of
+  it: rows that tie may come in either order) is bad too.  Limit 0.  (The
+  graph route is approximate: a short answer counts against its recall,
+  not here, and so does an empty one for a filter under 1%, which only the
+  selector's sampled estimate sends to the graph.)
 * ``recall_miss`` -- 1 - the mean recall@k of the graph route's answers
   against the reference's top-k ids: what a traversal that stops early,
   ignores ``ef`` or prunes wrongly loses, however well each returned row
   agrees with its own distance.
+* ``brute_recall_miss`` -- only where the brute route is compressed (it
+  scans PQ or SQ codes and re-ranks the best ``rerank * k`` exactly): 1 -
+  the mean recall@k of the brute route's answers.  Such a route promises
+  recall, not exactness, so its far rows count here and not in
+  ``bad_answers``.  The cell's limits must give it a limit.
 * ``dist_err`` -- the mean gap between a returned distance and the exact
   distance of the returned row, over every answer and rank, each as a
   share of the exact k-th distance of that request.  The configuration
@@ -97,11 +103,13 @@ def recall_at_k(got: np.ndarray, ref_ids: np.ndarray, k: int) -> np.ndarray:
 
 def compare(ans_items: np.ndarray, ans_ids: np.ndarray, ans_d: np.ndarray,
             ans_brute: np.ndarray, n_unanswered: int, vecs, ints, floats,
-            cols: dict, pool, k: int) -> tuple[dict, dict]:
+            cols: dict, pool, k: int, *,
+            brute_exact: bool) -> tuple[dict, dict]:
     """Check every answer (pool item ``ans_items[a]`` answered with
     ``ans_ids[a]`` / ``ans_d[a]``, by the brute route where
-    ``ans_brute[a]``) against the exact reference.  Returns (numbers
-    compared, diagnostics including the mean recall@k)."""
+    ``ans_brute[a]``) against the exact reference, holding the brute route
+    to exactness where ``brute_exact`` and to its recall otherwise.
+    Returns (numbers compared, diagnostics including the mean recall@k)."""
     n = vecs.shape[0]
     uniq, inv = np.unique(ans_items, return_inverse=True)
     ref = exact_topk(vecs, ints, floats, cols, pool, k, uniq)
@@ -116,7 +124,8 @@ def compare(ans_items: np.ndarray, ans_ids: np.ndarray, ans_d: np.ndarray,
     dup = (srt[:, 1:] == srt[:, :-1]).any(1)
     short = n_ret < np.minimum(k, n_match)
     empty = (n_ret == 0) & (n_match >= GRAPH_SERVES * n) & (n_match > 0)
-    short_brute = short & np.asarray(ans_brute, bool)
+    brute = np.asarray(ans_brute, bool)
+    short_brute = short & brute
     safe = np.clip(ids, 0, n - 1)
     fails = np.zeros(len(ids), bool)
     for fi in np.unique(pool.filter_of[ans_items]):
@@ -144,15 +153,21 @@ def compare(ans_items: np.ndarray, ans_ids: np.ndarray, ans_d: np.ndarray,
         err_sum += float((gap / np.maximum(kth[sl], 1e-30)[:, None]).sum())
         n_rows += int(ok.sum())
         far[sl] = (ok & (true_d > kth[sl, None] * (1.0 + TIE))).any(1)
-    far_brute = far & np.asarray(ans_brute, bool)
-    bad = oor | dup | fails | unsorted | empty | short_brute | far_brute
+    far_brute = far & brute
+    bad = oor | dup | fails | unsorted | empty | short_brute
+    if brute_exact:
+        bad |= far_brute
     recall = recall_at_k(ids, ref_ids, k)
-    graph = ~np.asarray(ans_brute, bool)
+
+    def miss(rows: np.ndarray) -> float:
+        return float(1.0 - recall[rows].mean()) if rows.any() else 0.0
+
     numbers = {"unanswered": int(n_unanswered), "bad_answers": int(bad.sum()),
-               "recall_miss": float(1.0 - recall[graph].mean())
-               if graph.any() else 0.0,
+               "recall_miss": miss(~brute),
                "dist_err": err_sum / max(n_rows, 1)}
-    diag = {"answers": int(len(ids)), "graph_answers": int(graph.sum()),
+    if not brute_exact:
+        numbers["brute_recall_miss"] = miss(brute)
+    diag = {"answers": int(len(ids)), "graph_answers": int((~brute).sum()),
             "recall": float(recall.mean()) if len(ids) else float("nan"),
             "out_of_range": int(oor.sum()), "duplicate": int(dup.sum()),
             "short": int(short.sum()), "short_brute": int(short_brute.sum()),
@@ -163,6 +178,9 @@ def compare(ans_items: np.ndarray, ans_ids: np.ndarray, ans_d: np.ndarray,
 
 
 def judge(numbers: dict, limits: dict) -> bool:
+    missing = sorted(set(numbers) - set(limits))
+    if missing:
+        raise KeyError(f"the cell's limits give no limit for {missing}")
     return all(numbers[name] <= limits[name] for name in numbers)
 
 

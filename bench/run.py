@@ -259,9 +259,12 @@ def main(argv=None, expect_platform: str = "tpu",
     ans_brute = np.asarray([log.routes[i] == "brute"
                             for i in np.nonzero(answered)[0]], bool)
     cols = workload.column_index(cfg)
+    # the program's brute route scans compressed codes where ``use_pq``
+    # (``LocalBackend.search_brute``): it promises recall, not exactness
     numbers, diag = reference.compare(items, ans_ids, ans_d, ans_brute,
                                       unanswered, vecs, ints, floats, cols,
-                                      pool, k)
+                                      pool, k,
+                                      brute_exact=not cfg["search"]["use_pq"])
     correct = reference.judge(numbers, cell.limits)
 
     routes = [r for r in log.routes if r is not None]

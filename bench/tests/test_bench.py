@@ -272,13 +272,15 @@ def test_control_is_not_correct(cell):
     k = cfg["search"]["k"]
     ids, d = reference.control_answers(vecs, ints, floats, cols, pool, k, items)
     numbers, _ = reference.compare(items, ids, d, np.ones(len(items), bool), 0,
-                                   vecs, ints, floats, cols, pool, k)
+                                   vecs, ints, floats, cols, pool, k,
+                                   brute_exact=True)
     assert not reference.judge(numbers, c.limits), numbers
     # the same scan with an exact dot product is correct
     ref = reference.exact_topk(vecs, ints, floats, cols, pool, k, items)
     numbers, diag = reference.compare(items, ref["ids"], ref["d"],
                                       np.ones(len(items), bool), 0, vecs,
-                                      ints, floats, cols, pool, k)
+                                      ints, floats, cols, pool, k,
+                                      brute_exact=True)
     assert reference.judge(numbers, c.limits) and diag["recall"] == 1.0
 
 
@@ -291,29 +293,118 @@ def _tiny_inputs(cell: str, pool_size: int = 64):
     return c, vecs, ints, floats, pool, workload.column_index(cfg)
 
 
-@pytest.mark.parametrize("route", ["graph", "brute"])
-def test_valid_rows_that_are_not_the_nearest_are_caught(route):
+def _compressed_limits(limits: dict) -> dict:
+    """The cell's limits with a stand-in for ``brute_recall_miss``, which a
+    configuration with a compressed brute route sets from its own chip
+    readings: the graph route's recall limit."""
+    return dict(limits, brute_recall_miss=limits["recall_miss"])
+
+
+@pytest.mark.parametrize("route,brute_exact", [
+    pytest.param("graph", True, id="graph"),
+    pytest.param("brute", True, id="brute"),
+    pytest.param("graph", False, id="graph-compressed"),
+    pytest.param("brute", False, id="brute-compressed"),
+])
+def test_valid_rows_that_are_not_the_nearest_are_caught(route, brute_exact):
     """Rows that pass the filter, sorted, each with its true distance, but
     not the k nearest: a traversal that stops early, or a scan that keeps
-    the wrong rows."""
+    the wrong rows.  An exact scan promises the nearest rows, so they are
+    bad answers; a compressed scan promises recall, so they count in
+    ``brute_recall_miss`` and nowhere else."""
     cell = CELLS[0]
     c, vecs, ints, floats, pool, cols = _tiny_inputs(cell)
+    limits = c.limits if brute_exact else _compressed_limits(c.limits)
     k = c.config["search"]["k"]
     items = np.arange(pool.size)
     wide = reference.exact_topk(vecs, ints, floats, cols, pool, 3 * k, items)
     assert np.all(wide["n_match"] >= 3 * k)
     ids, d = wide["ids"][:, 2 * k:], wide["d"][:, 2 * k:]  # ranks 2k..3k-1
     brute = np.full(len(items), route == "brute")
-    numbers, _ = reference.compare(items, ids, d, brute, 0, vecs, ints,
-                                   floats, cols, pool, k)
+    numbers, diag = reference.compare(items, ids, d, brute, 0, vecs, ints,
+                                      floats, cols, pool, k,
+                                      brute_exact=brute_exact)
     assert numbers["dist_err"] < 1e-9
-    assert not reference.judge(numbers, c.limits), numbers
-    key = "bad_answers" if route == "brute" else "recall_miss"
-    assert numbers[key] > c.limits[key]
+    assert not reference.judge(numbers, limits), numbers
+    if route == "graph":
+        key = "recall_miss"
+    else:
+        key = "bad_answers" if brute_exact else "brute_recall_miss"
+        assert diag["far_brute"] == len(items)
+    assert numbers[key] > limits[key]
+    if route == "brute" and not brute_exact:
+        assert numbers["bad_answers"] == 0 and numbers["recall_miss"] == 0.0
     # the true top-k, with ties broken either way, is correct
     numbers, _ = reference.compare(items, wide["ids"][:, :k], wide["d"][:, :k],
-                                   brute, 0, vecs, ints, floats, cols, pool, k)
-    assert reference.judge(numbers, c.limits), numbers
+                                   brute, 0, vecs, ints, floats, cols, pool, k,
+                                   brute_exact=brute_exact)
+    assert reference.judge(numbers, limits), numbers
+
+
+@pytest.mark.parametrize("fault,diag_key", [
+    ("filter", "filter_fail"), ("out_of_range", "out_of_range"),
+    ("duplicate", "duplicate"), ("short", "short_brute"),
+    ("unsorted", "unsorted"), ("empty", "empty"),
+])
+def test_compressed_brute_route_keeps_every_rule_but_the_far_row(fault,
+                                                                 diag_key):
+    """A compressed brute route is excused its far rows only: an answer
+    that fails its filter, names a row out of range or twice, stops short
+    of ``min(k, rows that pass)``, is unsorted or empty is still bad."""
+    c, vecs, ints, floats, pool, cols = _tiny_inputs(CELLS[0])
+    k = c.config["search"]["k"]
+    n = vecs.shape[0]
+    items = np.arange(pool.size)
+    ref = reference.exact_topk(vecs, ints, floats, cols, pool, k, items)
+    ids, d = ref["ids"].copy(), ref["d"].copy()
+    if fault == "filter":          # the farthest row that fails the filter
+        mask = workload.eval_filter(pool.filters[pool.filter_of[0]], ints,
+                                    floats, cols)
+        true_d = np.linalg.norm(vecs.astype(np.float64)
+                                - pool.queries[0].astype(np.float64), axis=1)
+        ids[0, -1] = int(np.argmax(np.where(mask, -1.0, true_d)))
+        d[0, -1] = true_d[ids[0, -1]]
+    elif fault == "out_of_range":
+        ids[0, -1] = n
+    elif fault == "duplicate":
+        ids[0, -1], d[0, -1] = ids[0, -2], d[0, -2]
+    elif fault == "short":
+        ids[0, -1], d[0, -1] = -1, np.inf
+    elif fault == "unsorted":
+        ids[0, [0, -1]], d[0, [0, -1]] = ids[0, [-1, 0]], d[0, [-1, 0]]
+    else:
+        ids[0], d[0] = -1, np.inf
+    numbers, diag = reference.compare(items, ids, d, np.ones(len(items), bool),
+                                      0, vecs, ints, floats, cols, pool, k,
+                                      brute_exact=False)
+    assert diag[diag_key] == 1, diag
+    assert numbers["bad_answers"] == 1, numbers
+    assert not reference.judge(numbers, _compressed_limits(c.limits))
+
+
+@pytest.mark.parametrize("use_pq", [False, True])
+def test_checks_follow_the_brute_routes_promise(use_pq):
+    """An exact brute route keeps the four checks and their values; a
+    compressed one adds ``brute_recall_miss``, which its limits must give."""
+    c, vecs, ints, floats, pool, cols = _tiny_inputs(CELLS[0])
+    k = c.config["search"]["k"]
+    items = np.arange(pool.size)
+    ref = reference.exact_topk(vecs, ints, floats, cols, pool, k, items)
+    brute = np.arange(pool.size) % 2 == 0       # both routes answer
+    args = (items, ref["ids"], ref["d"], brute, 0, vecs, ints, floats, cols,
+            pool, k)
+    exact, _ = reference.compare(*args, brute_exact=True)
+    assert list(exact) == ["unanswered", "bad_answers", "recall_miss",
+                           "dist_err"]
+    numbers, _ = reference.compare(*args, brute_exact=not use_pq)
+    if not use_pq:
+        assert numbers == exact
+        return
+    assert {n: numbers[n] for n in exact} == exact
+    assert numbers["brute_recall_miss"] == 0.0
+    with pytest.raises(KeyError, match="brute_recall_miss"):
+        reference.judge(numbers, c.limits)
+    assert reference.judge(numbers, _compressed_limits(c.limits))
 
 
 def _break(monkeypatch, route: str, fault: str):
@@ -357,6 +448,54 @@ def test_broken_path_is_not_correct(cache, monkeypatch, cell, route, fault):
     rc, last, out = run_cell(cell)
     assert rc == 0 and last is not None, out
     assert last["correct"] is False, last["checks"]
+
+
+def _pq(monkeypatch, cell: str) -> dict:
+    """Overrides that give a tiny cell the compressed brute route of a PQ
+    deployment (32 subspaces of 8 bits, as SIFT's PQ papers use) and send
+    every request down it, with a stand-in limit for its recall."""
+    import functools
+    import repro.core
+    monkeypatch.setattr(repro.core, "SearchOptions", functools.partial(
+        repro.core.SearchOptions, force="brute"))
+    load = workload.load_cell
+
+    def load_cell(name, root=workload.ROOT, overrides=None):
+        c = load(name, root, overrides)
+        return workload.Cell(c.name, c.config, c.traffic, c.chips,
+                             _compressed_limits(c.limits))
+
+    monkeypatch.setattr(workload, "load_cell", load_cell)
+    ov = tiny(cell)
+    ov["quant"] = {"kind": "pq", "m": 32, "nbits": 8, "train_iters": 2}
+    ov["search"] = dict(ov["search"], use_pq=True)
+    return ov
+
+
+def test_compressed_brute_route_is_judged_by_its_recall(cache, monkeypatch):
+    """Through ``run.main``: the PQ scan with its configured re-rank is
+    correct; with ``search.rerank = 0`` (``control.py --fault rerank_k``)
+    its far rows cost recall, and ``brute_recall_miss`` catches them while
+    ``bad_answers`` stays 0."""
+    import control
+    ov = _pq(monkeypatch, CELLS[0])
+    cell = workload.load_cell(CELLS[0])
+    sound, diag = control.served_readings(cell, SEED, 1.0, {}, "cpu",
+                                          overrides=ov)
+    assert diag["attempted"] > 0
+    assert reference.judge(sound, cell.limits), sound
+    fault, _ = control.rerank_k_readings(cell, SEED, 1.0, "cpu", overrides=ov)
+    assert fault["bad_answers"] == 0 and fault["recall_miss"] == 0.0
+    assert fault["dist_err"] <= cell.limits["dist_err"]
+    assert fault["brute_recall_miss"] > cell.limits["brute_recall_miss"], fault
+    assert fault["brute_recall_miss"] > 3 * sound["brute_recall_miss"]
+
+
+def test_rerank_k_needs_a_compressed_brute_route():
+    import control
+    with pytest.raises(SystemExit, match="no compressed brute route"):
+        control.rerank_k_readings(workload.load_cell(CELLS[0]), SEED, 1.0,
+                                  "cpu", overrides=tiny(CELLS[0]))
 
 
 def test_traversal_that_ignores_ef_is_not_correct(cache):
@@ -433,6 +572,24 @@ def test_window_registry_is_the_difference():
     assert w["counters"]["c"]["series"][""] == 7.0
     assert w["histograms"]["h"]["series"]['stage="x"'] == {"sum": 3.0, "count": 3}
     assert w["views"]["frontend"]["coalesce"] == {"dispatches": 4, "rows": 1024}
+
+
+def test_host_ms_per_batch_leaves_out_each_routes_dispatch():
+    """The ``graph`` and ``brute`` spans less their ``search`` children,
+    which enqueue the device work, with the other host stages."""
+    stages = {"compile": 0.010, "cache_lookup": 0.001, "route": 0.002,
+              "graph": 0.050, "graph/pad": 0.003, "graph/search": 0.040,
+              "brute": 0.020, "brute/search": 0.015, "estimate": 0.5}
+    reg = {"counters": {"favor_batches_total": {"series": {"": 10.0}}},
+           "histograms": {"favor_stage_seconds": {"series": {
+               f'stage="{k}"': {"sum": v, "count": 10}
+               for k, v in stages.items()}}},
+           "views": {}}
+    c = workload.load_cell(CELLS[0])
+    ctx = layer.Context(c.config, c.traffic, reg, None, "TPU v5 lite")
+    host = 0.010 + 0.001 + 0.002 + (0.050 - 0.040) + (0.020 - 0.015)
+    assert run.load_reader("host_ms_per_batch.closed")(ctx) == \
+        pytest.approx(1e3 * host / 10)
 
 
 def _ctx(cell, counters=None, kernels=None, calls=None):
