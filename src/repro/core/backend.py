@@ -25,7 +25,9 @@ Two implementations ship here:
                     shard_map body (previously LocalBackend-only).
 
 Both expose ``schema`` / ``sel_cfg`` so the router takes identical routing
-decisions regardless of where execution lands, and ``validate(opts)`` so
+decisions regardless of where execution lands (LocalBackend also confirms
+estimates near lambda by an exact count, ``exact_selectivity``; the sharded
+backend routes on the estimate alone), and ``validate(opts)`` so
 option/state mismatches (e.g. ``use_pq`` without a codebook) fail before any
 device work.  Future backends (caching, async, remote) implement the same
 three methods and plug into ``ServeEngine`` unchanged.
@@ -206,6 +208,20 @@ class LocalBackend:
             return jnp.ones((b,), jnp.float32)
         return selector.estimate_batched(programs, self.index.sample_ints,
                                          self.index.sample_floats)
+
+    @property
+    def sample_size(self) -> tuple[int, int]:
+        """(rows in the selectivity sample, base rows it was drawn from):
+        Eq. 1's n and N for the selector's check band."""
+        return int(self.index.sample_ints.shape[0]), int(self.index.index.n)
+
+    def exact_selectivity(self, programs: dict, valid=None):
+        """(B,) exact selectivity over every live base row (the selector's
+        check, router.check_estimates); pad rows' always-false programs
+        count 0, so ``valid`` is not needed."""
+        _, pn, pi, pf = self.index._pf
+        return selector.exact_batched(programs, pi, pf, pn,
+                                      chunk=self.index.prefbf_chunk)
 
     def search_graph(self, queries, programs: dict, p_hat,
                      opts: SearchOptions, valid=None) -> dict:

@@ -171,8 +171,8 @@ def unpad(n: int, *arrays):
 def route_key(kind: str, opts) -> tuple:
     """The jit-static identity of one backend entry point: shapes recorded
     under different keys correspond to genuinely different executables."""
-    if opts is None or kind == "estimate":
-        return ()  # the estimate executable is SearchConfig-independent
+    if opts is None or kind in ("estimate", "check"):
+        return ()  # the selectivity executables are SearchConfig-independent
     cfg = opts.search_config()
     if kind == "brute":
         return (cfg, opts.use_pq, opts.rerank)
@@ -246,7 +246,9 @@ def record(registry, kind: str, size: int, real: int, opts=None) -> None:
 # Explicit warm-up
 # ---------------------------------------------------------------------------
 def warmup(backend, opts, *, buckets=None, registry=None) -> tuple[int, ...]:
-    """Compile every (estimate / graph / brute, bucket) executable now.
+    """Compile every (estimate / check / graph / brute, bucket) executable
+    now (the selector's exact ``check`` only where the backend has one and
+    no route is forced).
 
     Drives the innermost backend (cache decorators are unwrapped -- their
     host-side layers never compile) with all-pad batches: zero queries,
@@ -285,6 +287,10 @@ def warmup(backend, opts, *, buckets=None, registry=None) -> tuple[int, ...]:
         valid = np.zeros((b,), bool)
         record(registry, "estimate", b, 0)
         np.asarray(target.estimate(progs))
+        check = getattr(target, "exact_selectivity", None)
+        if opts.force is None and check is not None:
+            record(registry, "check", b, 0)
+            np.asarray(check(progs, valid=valid))
         if opts.force != "brute":
             record(registry, "graph", b, 0, opts)
             out = target.search_graph(queries, progs,

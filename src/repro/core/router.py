@@ -45,6 +45,9 @@ class SearchResult:
     # for the query's sub-batch (every lane in a stage shares the wave
     # count, so this is a batch-shape diagnostic, not a per-lane hop count)
     waves: np.ndarray | None = None  # (B,) or None
+    # checked: the sampled estimate fell in the selector's check band and
+    # the exact match count decided the route (selector.check_band)
+    checked: np.ndarray | None = None  # (B,) bool or None
     elapsed_s: float = 0.0
 
     @property
@@ -79,6 +82,7 @@ class PendingExecution:
     path_td: np.ndarray
     waves: np.ndarray
     miss: np.ndarray
+    checked: np.ndarray | None = None
     tr: object = None
     obs: object = None
     programs: dict | None = None
@@ -145,15 +149,17 @@ class PendingExecution:
             self.hops if self.graph_diag else None,
             self.path_td if self.graph_diag else None,
             waves=self.waves if self.waves_diag else None,
-            elapsed_s=elapsed)
+            checked=self.checked, elapsed_s=elapsed)
         return self._result
 
 
 @dataclass(frozen=True)
 class RoutePlan:
-    """Per-query routing decision: True -> PreFBF brute scan."""
+    """Per-query routing decision: True -> PreFBF brute scan.  ``checked``
+    marks the queries an exact match count routed."""
     p_hat: np.ndarray
     brute: np.ndarray
+    checked: np.ndarray
 
     @property
     def graph_idx(self) -> np.ndarray:
@@ -184,23 +190,56 @@ def compile_programs(filters, schema: F.Schema, batch: int,
 
 
 def plan_routes(p_hat: np.ndarray, lam: float,
-                force: str | None = None) -> RoutePlan:
-    """Route each query by estimated selectivity (p_hat < lambda -> brute);
-    ``force`` pins every query to one route (validated, not pattern-matched:
-    a typo'd route name raises instead of silently auto-routing)."""
+                force: str | None = None,
+                p_exact: np.ndarray | None = None) -> RoutePlan:
+    """Route each query by estimated selectivity (p_hat < lambda -> brute),
+    or by its exact selectivity where ``p_exact`` holds one (NaN elsewhere;
+    see ``selector.route``); ``force`` pins every query to one route
+    (validated, not pattern-matched: a typo'd route name raises instead of
+    silently auto-routing)."""
     if force not in ROUTES:
         raise ValueError(f"force must be one of {ROUTES}, got {force!r}")
     # a NaN estimate (empty selectivity sample: freshly-created live index
     # with no merged base) routes as p_hat=1 -- graph side, where the
     # delta-compose path serves it
     p_hat = np.nan_to_num(np.asarray(p_hat, np.float32), nan=1.0)
+    checked = np.zeros(p_hat.shape, bool)
     if force == "brute":
         brute = np.ones(p_hat.shape, bool)
     elif force == "graph":
         brute = np.zeros(p_hat.shape, bool)
     else:
-        brute = selector.route(p_hat, lam)
-    return RoutePlan(p_hat, brute)
+        brute = selector.route(p_hat, lam, p_exact)
+        if p_exact is not None:
+            checked = ~np.isnan(np.asarray(p_exact))
+    return RoutePlan(p_hat, brute, checked)
+
+
+def check_estimates(backend, programs: dict, valid, p_hat: np.ndarray, *,
+                    span, registry=None) -> np.ndarray | None:
+    """Exact selectivity of the queries whose estimate falls in the
+    selector's check band (``selector.check_band``), NaN for the rest; None
+    when the band is empty or the backend counts no exact matches (it has
+    no ``exact_selectivity``), so neither dispatches anything.
+
+    ``programs`` / ``valid`` are the estimate's own (bucket-padded) batch:
+    the count runs over all of it, at a shape warm-up compiled, and the band
+    picks the rows it decides -- slicing the band out would compile a new
+    program for every band size."""
+    check = getattr(backend, "exact_selectivity", None)
+    if check is None:
+        return None
+    n, total = backend.sample_size
+    band = selector.check_band(p_hat, n, total, backend.sel_cfg.lam)
+    if not band.any():
+        return None
+    with span("check", rows=int(band.sum())):
+        # the tenant sidecar is host-side cache state, never a device input
+        progs = {k: v for k, v in programs.items() if k != "scope"}
+        batching.record(registry, "check", int(progs["valid"].shape[0]),
+                        int(band.sum()))
+        exact = np.asarray(check(progs, valid=valid))[:len(p_hat)]
+    return np.where(band, exact, np.nan).astype(np.float32)
 
 
 def take_programs(programs: dict, idx: np.ndarray) -> dict:
@@ -251,8 +290,10 @@ def execute(backend, queries, filters, opts: SearchOptions, *,
     bucket shape, pad fraction, cache hits), each of which -- when the spec
     enables annotations -- is also a ``jax.profiler.TraceAnnotation`` on
     the profiler's clock.  The estimate splits into ``dispatch`` (pad,
-    enqueue) and ``wait`` (the host sync on its result); ``finish`` adds
-    ``fetch``, the device-to-host copies of the route outputs.
+    enqueue), ``wait`` (the host sync on its result) and, when the
+    selector's check band holds rows, ``check`` (the exact count's
+    dispatch and sync); ``finish`` adds ``fetch``, the device-to-host
+    copies of the route outputs.
     Obs hooks only *observe*; results are bit-identical with obs absent,
     disabled, or sampled out.
 
@@ -326,6 +367,7 @@ def execute(backend, queries, filters, opts: SearchOptions, *,
                 if spec is None:
                     batching.record(registry, "estimate", len(miss),
                                     len(miss))
+                    eprogs, evalid = mprogs, None
                     est = backend.estimate(mprogs)
                 else:
                     eprogs, evalid = batching.pad_programs(spec, mprogs)
@@ -336,13 +378,21 @@ def execute(backend, queries, filters, opts: SearchOptions, *,
                     est = backend.estimate(eprogs, valid=evalid)
             with _span("wait"):
                 mp_hat = np.asarray(est)[:len(miss)]
+            p_exact = (check_estimates(backend, eprogs, evalid, mp_hat,
+                                       registry=registry, span=_span)
+                       if opts.force is None else None)
         with _span("route") as sp:
-            plan = plan_routes(mp_hat, backend.sel_cfg.lam, opts.force)
+            plan = plan_routes(mp_hat, backend.sel_cfg.lam, opts.force,
+                               p_exact)
             if sp is not None:
                 sp.attrs["graph"] = int(len(plan.graph_idx))
                 sp.attrs["brute"] = int(len(plan.brute_idx))
+                sp.attrs["checked"] = int(plan.checked.sum())
         p_hat[miss] = plan.p_hat
         routed_brute[miss] = plan.brute
+        checked = np.zeros((b,), bool)
+        checked[miss] = plan.checked
+        pend.checked = checked
 
         gi, bi = plan.graph_idx, plan.brute_idx
         pend.mq, pend.mprogs, pend.mp_hat = mq, mprogs, mp_hat

@@ -10,9 +10,14 @@ hyper-geometric distribution, the relative error of ``p_hat`` is (Eq. 1)
 
     rel_err = sqrt((1-p) / (n p) * (1 - n/N))
 
-which stays around 1% for million-scale datasets at a 1% sampling rate down to
-p ~ 1%; below that the selector routes to PreFBF anyway (whose execution does
-not consume ``p_hat``), so estimator error there is inconsequential.
+At a 1% sample of a million rows (n = 10,000) that is about 1% at p = 50%,
+3% at p = 10% and 9.9% at p = 1%; at the 328-row sample of 2^15 rows it is
+55% at p = 1%.  So near the routing threshold lambda the estimate alone
+cannot tell a filter just under lambda from one just over it: a 0.5% filter
+reads 1% or more in about one sample in twelve at n = 328.  Error there is
+not harmless -- a sub-lambda filter sent to the graph loses most of its
+recall -- which is why the selector confirms estimates near lambda with an
+exact count (selector.py).
 """
 from __future__ import annotations
 
@@ -29,11 +34,15 @@ def sample_indices(n: int, rate: float = 0.01, min_size: int = 256,
     return np.sort(rng.choice(n, size=size, replace=False)).astype(np.int32)
 
 
-def relative_error(n: int, p: float, total: int) -> float:
-    """Eq. 1: hyper-geometric relative error of the sampled estimate."""
-    if p <= 0.0:
-        return float("inf")
-    return float(np.sqrt((1.0 - p) / (n * p) * max(0.0, 1.0 - n / total)))
+def relative_error(n: int, p, total: int):
+    """Eq. 1: hyper-geometric relative error of the sampled estimate
+    (inf at p = 0).  ``p`` may be a scalar (returns a float) or an array."""
+    p_arr = np.asarray(p, np.float64)
+    with np.errstate(divide="ignore", invalid="ignore"):
+        err = np.sqrt((1.0 - p_arr) / (n * p_arr)
+                      * max(0.0, 1.0 - n / total))
+    err = np.where(p_arr > 0.0, err, np.inf)
+    return float(err) if err.ndim == 0 else err
 
 
 def estimate_selectivity(program, sample_ints, sample_floats, xp=np):

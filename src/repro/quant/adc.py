@@ -127,7 +127,11 @@ def pq_prefbf_topk(codes, norms, ints, floats, queries, programs, centroids,
     float32 dists (B, k) (+inf missing).
     """
     r = max(k, rerank * k)
-    luts = build_luts(centroids, queries)
+    # brute.luts / brute.rerank: profiler scopes around the work outside
+    # the scan, read from an operation's name stack (favor.pq_adc_topr
+    # names the kernel itself)
+    with jax.named_scope("brute.luts"):
+        luts = build_luts(centroids, queries)
     if use_pallas:
         from ..kernels.pq_adc import ops as pq_ops
         # the kernel's VMEM budget is sized for bn<=512 tiles (it builds a
@@ -138,7 +142,9 @@ def pq_prefbf_topk(codes, norms, ints, floats, queries, programs, centroids,
     else:
         _, cand_i = _adc_scan(codes, norms, ints, floats, luts, programs,
                               r=r, chunk=chunk)
-    return _exact_rerank(vectors, norms, queries, cand_i, k=k, valid=valid)
+    with jax.named_scope("brute.rerank"):
+        return _exact_rerank(vectors, norms, queries, cand_i, k=k,
+                             valid=valid)
 
 
 @partial(jax.jit, static_argnames=("k", "rerank", "chunk"))

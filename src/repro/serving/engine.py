@@ -182,6 +182,12 @@ class ServeEngine:
         self._m_requests = reg.counter(
             "favor_requests_total", "Requests served, by route",
             labels=("route",))
+        self._m_checked = reg.counter(
+            "favor_route_checked_total",
+            "Requests whose sampled estimate fell in the selector's check "
+            "band, by the route their exact match count gave: outcome "
+            "brute is a request the check moved off the graph route",
+            labels=("outcome",))
         self._m_batches = reg.counter(
             "favor_batches_total", "Engine batches dispatched")
         self._m_latency = reg.histogram(
@@ -523,6 +529,8 @@ class ServeEngine:
             for i, r in enumerate(batch):
                 route = "brute" if res.routed_brute[i] else "graph"
                 self._m_requests.inc(route=route)
+                if res.checked is not None and res.checked[i]:
+                    self._m_checked.inc(outcome=route)
                 # waves==0 means no traversal ran for this lane (cache
                 # hit): keep those out of the traversal-depth histogram
                 if (res.waves is not None and route == "graph"

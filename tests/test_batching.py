@@ -302,8 +302,9 @@ def test_engine_warmup_bounds_compiled_shapes(small_index, small_dataset):
     ladder = eng.warmup()
     assert ladder == SPEC.buckets()
     st0 = eng.stats["batching"]
-    # estimate + graph + brute, one executable per bucket
-    assert st0["compiled_shapes"] == 3 * len(ladder)
+    # estimate + the selector's exact check + graph + brute, one
+    # executable per bucket
+    assert st0["compiled_shapes"] == 4 * len(ladder)
     qs, flts = _workload(schema, vecs.shape[1], 29, seed=13)
     for q, f in zip(qs, flts):
         eng.submit(q, f)
@@ -332,7 +333,7 @@ def test_engine_warmup_unwraps_cache_and_custom_buckets(small_index):
     eng = ServeEngine(cb, OPTS_B, max_batch=16)
     assert eng.warmup(buckets=(4, 8)) == (4, 8)
     st = eng.stats["batching"]
-    assert st["compiled_shapes"] == 3 * 2
+    assert st["compiled_shapes"] == 4 * 2
     # warmup drove the inner backend: no cache-layer counter pollution
     cs = eng.stats["cache"]
     assert cs["semantic"]["misses"] == 0 and cs["selectivity"]["misses"] == 0

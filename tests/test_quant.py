@@ -87,6 +87,7 @@ def test_adc_distance_error_bound():
     (700, 6, 8, 6, 20, 4, 128),    # non-multiple row count (padding path)
     (1024, 8, 4, 8, 10, 8, 256),
     (512, 4, 16, 4, 40, 4, 512),   # one n-tile, large R
+    (768, 8, 32, 8, 40, 8, 256),   # the sift128-pq widths: PQ32, K=256, R=40
 ])
 def test_pq_adc_kernel_matches_ref(n, b, m, nbits, r, bq, bn):
     rng = np.random.default_rng(n + m)
