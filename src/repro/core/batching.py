@@ -279,11 +279,10 @@ def warmup(backend, opts, *, buckets=None, registry=None) -> tuple[int, ...]:
     while inner is not None:
         target, inner = inner, getattr(inner, "inner", None)
     dim = int(target.dim)
-    fp = F.compile_filter(F.FalseFilter(), target.schema)
     for b in bucket_list:
         queries = jnp.zeros((b, dim), jnp.float32)
-        progs = {k: jnp.asarray(v)
-                 for k, v in F.stack_programs([fp] * b).items()}
+        progs = {k: jnp.asarray(v) for k, v in F.compile_batch(
+            [F.FalseFilter()] * b, target.schema).items()}
         valid = np.zeros((b,), bool)
         record(registry, "estimate", b, 0)
         np.asarray(target.estimate(progs))

@@ -29,6 +29,7 @@ from __future__ import annotations
 import dataclasses
 import hashlib
 import math
+import operator
 from dataclasses import dataclass
 from typing import Sequence
 
@@ -183,153 +184,6 @@ class Not(Filter):
 
 
 # ---------------------------------------------------------------------------
-# Conjunction representation used during compilation
-# ---------------------------------------------------------------------------
-@dataclass
-class _Conj:
-    imask: np.ndarray  # (m_i,) uint32 allowed-value bitmasks
-    flo: np.ndarray  # (m_f,) float32
-    fhi: np.ndarray  # (m_f,) float32
-
-    def copy(self) -> "_Conj":
-        return _Conj(self.imask.copy(), self.flo.copy(), self.fhi.copy())
-
-    def feasible(self) -> bool:
-        return bool(np.all(self.imask != 0) and np.all(self.flo <= self.fhi))
-
-
-def _full_conj(schema: Schema) -> _Conj:
-    m_i = len(schema.int_columns)
-    m_f = len(schema.float_columns)
-    imask = np.zeros((m_i,), np.uint32)
-    for j, c in enumerate(schema.int_columns):
-        imask[j] = np.uint32((1 << c.vocab) - 1)
-    flo = np.full((m_f,), -np.inf, np.float32)
-    fhi = np.full((m_f,), np.inf, np.float32)
-    return _Conj(imask, flo, fhi)
-
-
-def _int_bits(values: Sequence[int], vocab: int, column: str) -> np.uint32:
-    mask = np.uint32(0)
-    for v in values:
-        v = int(v)
-        if not (0 <= v < vocab):
-            raise ValueError(f"value {v} out of vocab [0,{vocab}) for column {column!r}")
-        mask |= np.uint32(1) << np.uint32(v)
-    return mask
-
-
-def _strict_below(x: float) -> float:
-    return float(np.nextafter(np.float32(x), np.float32(-np.inf)))
-
-
-def _strict_above(x: float) -> float:
-    return float(np.nextafter(np.float32(x), np.float32(np.inf)))
-
-
-def _leaf_conjs(f: Filter, schema: Schema, negated: bool) -> list[_Conj]:
-    """Compile a (possibly negated) leaf to a list of conjunctions (a DNF)."""
-    if isinstance(f, TrueFilter):
-        return [] if negated else [_full_conj(schema)]
-    if isinstance(f, FalseFilter):
-        return [_full_conj(schema)] if negated else []
-
-    if isinstance(f, Equality):
-        col = schema.column(f.column)
-        if col.kind in INT_KINDS:
-            j = schema.int_index(f.column)
-            bits = _int_bits([int(f.value)], col.vocab, f.column)
-            c = _full_conj(schema)
-            c.imask[j] = ~bits & c.imask[j] if negated else bits
-            return [c]
-        j = schema.float_index(f.column)
-        v = float(f.value)
-        if not negated:
-            c = _full_conj(schema)
-            c.flo[j], c.fhi[j] = v, v
-            return [c]
-        lo_c, hi_c = _full_conj(schema), _full_conj(schema)
-        lo_c.fhi[j] = _strict_below(v)
-        hi_c.flo[j] = _strict_above(v)
-        return [lo_c, hi_c]
-
-    if isinstance(f, Inclusion):
-        col = schema.column(f.column)
-        if col.kind not in INT_KINDS:
-            # float inclusion == OR of equalities
-            dnf: list[_Conj] = []
-            for v in f.values:
-                dnf.extend(_leaf_conjs(Equality(f.column, v), schema, False))
-            if negated:
-                raise ValueError("NOT(Inclusion) on float columns is not supported; "
-                                 "use Range complements")
-            return dnf
-        j = schema.int_index(f.column)
-        bits = _int_bits(f.values, col.vocab, f.column)
-        c = _full_conj(schema)
-        full = c.imask[j]
-        c.imask[j] = (~bits & full) if negated else bits
-        return [c]
-
-    if isinstance(f, Range):
-        col = schema.column(f.column)
-        lo = -math.inf if f.lo is None else float(f.lo)
-        hi = math.inf if f.hi is None else float(f.hi)
-        if col.kind in INT_KINDS:
-            j = schema.int_index(f.column)
-            vals = [v for v in range(col.vocab) if lo <= v <= hi]
-            bits = _int_bits(vals, col.vocab, f.column)
-            c = _full_conj(schema)
-            full = c.imask[j]
-            c.imask[j] = (~bits & full) if negated else bits
-            return [c]
-        j = schema.float_index(f.column)
-        if not negated:
-            c = _full_conj(schema)
-            c.flo[j], c.fhi[j] = lo, hi
-            return [c]
-        out = []
-        if lo > -math.inf:
-            c = _full_conj(schema)
-            c.fhi[j] = _strict_below(lo)
-            out.append(c)
-        if hi < math.inf:
-            c = _full_conj(schema)
-            c.flo[j] = _strict_above(hi)
-            out.append(c)
-        return out
-
-    raise TypeError(f"not a leaf filter: {f!r}")
-
-
-def _conj_and(a: _Conj, b: _Conj) -> _Conj:
-    return _Conj(a.imask & b.imask, np.maximum(a.flo, b.flo), np.minimum(a.fhi, b.fhi))
-
-
-def _to_dnf(f: Filter, schema: Schema, negated: bool, max_width: int) -> list[_Conj]:
-    if isinstance(f, Not):
-        return _to_dnf(f.child, schema, not negated, max_width)
-    if isinstance(f, And) or isinstance(f, Or):
-        is_and = isinstance(f, And) != negated  # de Morgan
-        child_dnfs = [_to_dnf(c, schema, negated, max_width) for c in f.children]
-        if not is_and:
-            out = [c for d in child_dnfs for c in d]
-        else:
-            out = [_full_conj(schema)]
-            for d in child_dnfs:
-                out = [_conj_and(a, b) for a in out for b in d]
-                out = [c for c in out if c.feasible()]
-                if len(out) > 4 * max_width:
-                    raise ValueError(
-                        f"filter DNF exceeds width {max_width}; simplify the predicate")
-        out = [c for c in out if c.feasible()]
-        if len(out) > 4 * max_width:
-            raise ValueError(f"filter DNF exceeds width {max_width}")
-        return out
-    return [c for c in _leaf_conjs(f, schema, negated) if c.feasible()]
-
-
-# ---------------------------------------------------------------------------
 # Compiled program
 # ---------------------------------------------------------------------------
 @dataclass
@@ -352,22 +206,189 @@ class FilterProgram:
         return int(self.valid.shape[0])
 
 
+# ---------------------------------------------------------------------------
+# Compiler: AST -> DNF over Python scalars -> one array fill per batch
+#
+# The engine compiles every request of a batch on its host phase, so the
+# lowering runs on plain Python scalars and touches numpy once per field per
+# batch.  A conjunction is a tuple ``(masks, los, his)``: one int bitmask per
+# int column and one float bound pair per float column.  Float constants are
+# rounded to float32 when they enter a conjunction, so feasibility
+# (``lo <= hi``) is decided on the bounds the device compares against; max
+# and min commute with that rounding, so the arrays come out exactly as an
+# elementwise float32 lowering would write them.
+# ---------------------------------------------------------------------------
+def _int_bits(values: Sequence[int], vocab: int, column: str) -> int:
+    mask = 0
+    for v in values:
+        v = int(v)
+        if not (0 <= v < vocab):
+            raise ValueError(f"value {v} out of vocab [0,{vocab}) for column {column!r}")
+        mask |= 1 << v
+    return mask
+
+
+def _f32(x: float) -> float:
+    return float(np.float32(x))
+
+
+def _strict_below(x: float) -> float:
+    return float(np.nextafter(np.float32(x), np.float32(-np.inf)))
+
+
+def _strict_above(x: float) -> float:
+    return float(np.nextafter(np.float32(x), np.float32(np.inf)))
+
+
+def _feasible(c) -> bool:
+    return 0 not in c[0] and all(map(operator.le, c[1], c[2]))
+
+
+def _conj_and(a, b):
+    # max(b, a) / min(b, a) keep b on a tie, as np.maximum / np.minimum do:
+    # the sign of a zero bound is that of the later conjunct
+    return (tuple(map(operator.and_, a[0], b[0])), tuple(map(max, b[1], a[1])),
+            tuple(map(min, b[2], a[2])))
+
+
+class _Lowering:
+    """Negation normal form and DNF of filter ASTs over one schema."""
+
+    def __init__(self, schema: Schema, max_width: int):
+        self.masks = tuple((1 << c.vocab) - 1 for c in schema.int_columns)
+        m_f = len(schema.float_columns)
+        self.los, self.his = (-math.inf,) * m_f, (math.inf,) * m_f
+        self.full = (self.masks, self.los, self.his)
+        # the empty conjunction: no disjunct when an int column has no values
+        self.top = [self.full] if _feasible(self.full) else []
+        self.cols = {c.name: (True, j, c.vocab)
+                     for j, c in enumerate(schema.int_columns)}
+        self.cols.update({c.name: (False, j, None)
+                          for j, c in enumerate(schema.float_columns)})
+        self.max_width = max_width
+
+    def _mask(self, j: int, bits: int, negated: bool):
+        full = self.masks[j]
+        m = self.masks[:j] + ((~bits & full) if negated else bits,) + self.masks[j + 1:]
+        return (m, self.los, self.his)
+
+    def _bounds(self, j: int, lo: float, hi: float):
+        return (self.masks, self.los[:j] + (lo,) + self.los[j + 1:],
+                self.his[:j] + (hi,) + self.his[j + 1:])
+
+    def leaf(self, f: Filter, negated: bool) -> list:
+        """A (possibly negated) leaf as a list of conjunctions (a DNF).
+        The leaf classes are disjoint; the most common are tested first."""
+        if isinstance(f, Range):
+            is_int, j, vocab = self.cols[f.column]
+            lo = -math.inf if f.lo is None else float(f.lo)
+            hi = math.inf if f.hi is None else float(f.hi)
+            if is_int:
+                vals = [v for v in range(vocab) if lo <= v <= hi]
+                return [self._mask(j, _int_bits(vals, vocab, f.column), negated)]
+            if not negated:
+                return [self._bounds(j, _f32(lo), _f32(hi))]
+            out = []
+            if lo > -math.inf:
+                out.append(self._bounds(j, -math.inf, _strict_below(lo)))
+            if hi < math.inf:
+                out.append(self._bounds(j, _strict_above(hi), math.inf))
+            return out
+
+        if isinstance(f, Equality):
+            is_int, j, vocab = self.cols[f.column]
+            if is_int:
+                return [self._mask(j, _int_bits([int(f.value)], vocab, f.column),
+                                   negated)]
+            v = float(f.value)
+            if not negated:
+                v32 = _f32(v)
+                return [self._bounds(j, v32, v32)]
+            return [self._bounds(j, -math.inf, _strict_below(v)),
+                    self._bounds(j, _strict_above(v), math.inf)]
+
+        if isinstance(f, Inclusion):
+            is_int, j, vocab = self.cols[f.column]
+            if not is_int:
+                # float inclusion == OR of equalities
+                dnf = []
+                for v in f.values:
+                    v32 = _f32(float(v))
+                    dnf.append(self._bounds(j, v32, v32))
+                if negated:
+                    raise ValueError("NOT(Inclusion) on float columns is not supported; "
+                                     "use Range complements")
+                return dnf
+            return [self._mask(j, _int_bits(f.values, vocab, f.column), negated)]
+
+        if isinstance(f, TrueFilter):
+            return [] if negated else [self.full]
+        if isinstance(f, FalseFilter):
+            return [self.full] if negated else []
+
+        raise TypeError(f"not a leaf filter: {f!r}")
+
+    def dnf(self, f: Filter, negated: bool) -> list:
+        if isinstance(f, Not):
+            return self.dnf(f.child, not negated)
+        if isinstance(f, (And, Or)):
+            is_and = isinstance(f, And) != negated  # de Morgan
+            child_dnfs = [self.dnf(c, negated) for c in f.children]
+            limit = 4 * self.max_width
+            if not is_and:
+                out = [c for d in child_dnfs for c in d]
+            else:
+                out = self.top
+                for d in child_dnfs:
+                    out = [c for a in out for b in d
+                           if _feasible(c := _conj_and(a, b))]
+                    if len(out) > limit:
+                        raise ValueError(f"filter DNF exceeds width {self.max_width}; "
+                                         "simplify the predicate")
+            if len(out) > limit:
+                raise ValueError(f"filter DNF exceeds width {self.max_width}")
+            return out
+        return [c for c in self.leaf(f, negated) if _feasible(c)]
+
+
+def compile_batch(filters: Sequence[Filter], schema: Schema,
+                  width: int = 8) -> dict[str, np.ndarray]:
+    """One fixed-width DNF program per filter, stacked: ``valid`` (B, W),
+    ``imask`` (B, W, m_i), ``flo`` / ``fhi`` (B, W, m_f).  Dead rows are
+    infeasible padding (valid 0, imask 0, flo +inf, fhi -inf).  Raises
+    ValueError for the first filter whose DNF is wider than ``width``."""
+    low = _Lowering(schema, width)
+    rows, masks, los, his = [], [], [], []
+    for b, f in enumerate(filters):
+        conjs = low.dnf(f, False)
+        if len(conjs) > width:
+            raise ValueError(f"filter needs DNF width {len(conjs)} > {width}")
+        for w, (m, lo, hi) in enumerate(conjs):
+            rows.append(b * width + w)
+            masks.append(m)
+            los.append(lo)
+            his.append(hi)
+    n = len(filters)
+    m_i, m_f = len(low.masks), len(low.los)
+    valid = np.zeros((n * width,), np.float32)
+    imask = np.zeros((n * width, m_i), np.uint32)
+    flo = np.full((n * width, m_f), np.inf, np.float32)
+    fhi = np.full((n * width, m_f), -np.inf, np.float32)
+    if rows:
+        valid[rows] = 1.0
+        imask[rows] = np.array(masks, np.uint32).reshape(len(rows), m_i)
+        flo[rows] = np.array(los, np.float32).reshape(len(rows), m_f)
+        fhi[rows] = np.array(his, np.float32).reshape(len(rows), m_f)
+    return {"valid": valid.reshape(n, width),
+            "imask": imask.reshape(n, width, m_i),
+            "flo": flo.reshape(n, width, m_f),
+            "fhi": fhi.reshape(n, width, m_f)}
+
+
 def compile_filter(f: Filter, schema: Schema, width: int = 8) -> FilterProgram:
-    conjs = _to_dnf(f, schema, False, max_width=width)
-    if len(conjs) > width:
-        raise ValueError(f"filter needs DNF width {len(conjs)} > {width}")
-    m_i = len(schema.int_columns)
-    m_f = len(schema.float_columns)
-    valid = np.zeros((width,), np.float32)
-    imask = np.zeros((width, m_i), np.uint32)
-    flo = np.full((width, m_f), np.inf, np.float32)   # infeasible padding
-    fhi = np.full((width, m_f), -np.inf, np.float32)
-    for w, c in enumerate(conjs):
-        valid[w] = 1.0
-        imask[w] = c.imask
-        flo[w] = c.flo
-        fhi[w] = c.fhi
-    return FilterProgram(valid, imask, flo, fhi)
+    """One query's program: the single row of ``compile_batch([f])``."""
+    p = compile_batch([f], schema, width)
+    return FilterProgram(p["valid"][0], p["imask"][0], p["flo"][0], p["fhi"][0])
 
 
 # ---------------------------------------------------------------------------

@@ -181,12 +181,20 @@ def broadcast_filters(filters, batch: int) -> list:
     return filters
 
 
+def _no_span(name, **attrs):
+    return nullcontext()
+
+
 def compile_programs(filters, schema: F.Schema, batch: int,
-                     width: int = 8) -> dict:
-    """Compile + stack one DNF program per query (device-resident dict)."""
-    filters = broadcast_filters(filters, batch)
-    progs = [F.compile_filter(f, schema, width) for f in filters]
-    return {k: jnp.asarray(v) for k, v in F.stack_programs(progs).items()}
+                     width: int = 8, span=_no_span) -> dict:
+    """One DNF program per query, stacked and on the device: ``lower``
+    compiles the batch on the host (``filters.compile_batch``), ``upload``
+    copies its arrays to the device."""
+    with span("lower"):
+        progs = F.compile_batch(broadcast_filters(filters, batch), schema,
+                                width)
+    with span("upload"):
+        return {k: jnp.asarray(v) for k, v in progs.items()}
 
 
 def plan_routes(p_hat: np.ndarray, lam: float,
@@ -289,11 +297,12 @@ def execute(backend, queries, filters, opts: SearchOptions, *,
     batch, every pipeline stage below runs inside a span (wall time, route,
     bucket shape, pad fraction, cache hits), each of which -- when the spec
     enables annotations -- is also a ``jax.profiler.TraceAnnotation`` on
-    the profiler's clock.  The estimate splits into ``dispatch`` (pad,
-    enqueue), ``wait`` (the host sync on its result) and, when the
-    selector's check band holds rows, ``check`` (the exact count's
-    dispatch and sync); ``finish`` adds ``fetch``, the device-to-host
-    copies of the route outputs.
+    the profiler's clock.  The compile splits into ``lower`` (the batch's
+    filters to program arrays on the host) and ``upload``; the estimate
+    into ``dispatch`` (pad, enqueue), ``wait`` (the host sync on its
+    result) and, when the selector's check band holds rows, ``check`` (the
+    exact count's dispatch and sync); ``finish`` adds ``fetch``, the
+    device-to-host copies of the route outputs.
     Obs hooks only *observe*; results are bit-identical with obs absent,
     disabled, or sampled out.
 
@@ -309,14 +318,10 @@ def execute(backend, queries, filters, opts: SearchOptions, *,
     b = queries.shape[0]
 
     tr = obs.start_trace(b) if obs is not None else None
-    if tr is None:
-        def _span(name, **attrs):
-            return nullcontext()
-    else:
-        _span = tr.span
+    _span = _no_span if tr is None else tr.span
 
     with _span("compile", rows=b):
-        programs = compile_programs(filters, backend.schema, b)
+        programs = compile_programs(filters, backend.schema, b, span=_span)
     if scopes is not None and getattr(backend, "scope_aware", False):
         scopes = np.asarray(scopes, np.int32)
         if scopes.shape != (b,):

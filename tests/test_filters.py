@@ -1,4 +1,9 @@
 """Filter algebra + DNF compiler: unit and property tests."""
+import math
+import sys
+from dataclasses import dataclass
+from pathlib import Path
+
 import numpy as np
 import pytest
 
@@ -183,3 +188,269 @@ def test_property_equal_signature_implies_equal_semantics(f1, f2):
         np.testing.assert_array_equal(m1, m2)
     elif not np.array_equal(m1, m2):
         assert s1 != s2  # contrapositive (always true here; documents intent)
+
+
+# -- oracle: the per-filter lowering, one numpy conjunction at a time --------
+# ``compile_batch`` must write exactly the arrays this elementwise float32
+# lowering wrote, stacked by ``stack_programs``.
+@dataclass
+class _Conj:
+    imask: np.ndarray  # (m_i,) uint32 allowed-value bitmasks
+    flo: np.ndarray  # (m_f,) float32
+    fhi: np.ndarray  # (m_f,) float32
+
+    def feasible(self) -> bool:
+        return bool(np.all(self.imask != 0) and np.all(self.flo <= self.fhi))
+
+
+def _full_conj(schema):
+    m_i = len(schema.int_columns)
+    m_f = len(schema.float_columns)
+    imask = np.zeros((m_i,), np.uint32)
+    for j, c in enumerate(schema.int_columns):
+        imask[j] = np.uint32((1 << c.vocab) - 1)
+    flo = np.full((m_f,), -np.inf, np.float32)
+    fhi = np.full((m_f,), np.inf, np.float32)
+    return _Conj(imask, flo, fhi)
+
+
+def _int_bits(values, vocab, column):
+    mask = np.uint32(0)
+    for v in values:
+        v = int(v)
+        if not (0 <= v < vocab):
+            raise ValueError(f"value {v} out of vocab [0,{vocab}) for column {column!r}")
+        mask |= np.uint32(1) << np.uint32(v)
+    return mask
+
+
+def _strict_below(x):
+    return float(np.nextafter(np.float32(x), np.float32(-np.inf)))
+
+
+def _strict_above(x):
+    return float(np.nextafter(np.float32(x), np.float32(np.inf)))
+
+
+def _leaf_conjs(f, schema, negated):
+    if isinstance(f, F.TrueFilter):
+        return [] if negated else [_full_conj(schema)]
+    if isinstance(f, F.FalseFilter):
+        return [_full_conj(schema)] if negated else []
+
+    if isinstance(f, F.Equality):
+        col = schema.column(f.column)
+        if col.kind in F.INT_KINDS:
+            j = schema.int_index(f.column)
+            bits = _int_bits([int(f.value)], col.vocab, f.column)
+            c = _full_conj(schema)
+            c.imask[j] = ~bits & c.imask[j] if negated else bits
+            return [c]
+        j = schema.float_index(f.column)
+        v = float(f.value)
+        if not negated:
+            c = _full_conj(schema)
+            c.flo[j], c.fhi[j] = v, v
+            return [c]
+        lo_c, hi_c = _full_conj(schema), _full_conj(schema)
+        lo_c.fhi[j] = _strict_below(v)
+        hi_c.flo[j] = _strict_above(v)
+        return [lo_c, hi_c]
+
+    if isinstance(f, F.Inclusion):
+        col = schema.column(f.column)
+        if col.kind not in F.INT_KINDS:
+            dnf = []
+            for v in f.values:
+                dnf.extend(_leaf_conjs(F.Equality(f.column, v), schema, False))
+            if negated:
+                raise ValueError("NOT(Inclusion) on float columns is not supported; "
+                                 "use Range complements")
+            return dnf
+        j = schema.int_index(f.column)
+        bits = _int_bits(f.values, col.vocab, f.column)
+        c = _full_conj(schema)
+        full = c.imask[j]
+        c.imask[j] = (~bits & full) if negated else bits
+        return [c]
+
+    if isinstance(f, F.Range):
+        col = schema.column(f.column)
+        lo = -math.inf if f.lo is None else float(f.lo)
+        hi = math.inf if f.hi is None else float(f.hi)
+        if col.kind in F.INT_KINDS:
+            j = schema.int_index(f.column)
+            vals = [v for v in range(col.vocab) if lo <= v <= hi]
+            bits = _int_bits(vals, col.vocab, f.column)
+            c = _full_conj(schema)
+            full = c.imask[j]
+            c.imask[j] = (~bits & full) if negated else bits
+            return [c]
+        j = schema.float_index(f.column)
+        if not negated:
+            c = _full_conj(schema)
+            c.flo[j], c.fhi[j] = lo, hi
+            return [c]
+        out = []
+        if lo > -math.inf:
+            c = _full_conj(schema)
+            c.fhi[j] = _strict_below(lo)
+            out.append(c)
+        if hi < math.inf:
+            c = _full_conj(schema)
+            c.flo[j] = _strict_above(hi)
+            out.append(c)
+        return out
+
+    raise TypeError(f"not a leaf filter: {f!r}")
+
+
+def _conj_and(a, b):
+    return _Conj(a.imask & b.imask, np.maximum(a.flo, b.flo),
+                 np.minimum(a.fhi, b.fhi))
+
+
+def _to_dnf(f, schema, negated, max_width):
+    if isinstance(f, F.Not):
+        return _to_dnf(f.child, schema, not negated, max_width)
+    if isinstance(f, F.And) or isinstance(f, F.Or):
+        is_and = isinstance(f, F.And) != negated  # de Morgan
+        child_dnfs = [_to_dnf(c, schema, negated, max_width) for c in f.children]
+        if not is_and:
+            out = [c for d in child_dnfs for c in d]
+        else:
+            out = [_full_conj(schema)]
+            for d in child_dnfs:
+                out = [_conj_and(a, b) for a in out for b in d]
+                out = [c for c in out if c.feasible()]
+                if len(out) > 4 * max_width:
+                    raise ValueError(
+                        f"filter DNF exceeds width {max_width}; simplify the predicate")
+        out = [c for c in out if c.feasible()]
+        if len(out) > 4 * max_width:
+            raise ValueError(f"filter DNF exceeds width {max_width}")
+        return out
+    return [c for c in _leaf_conjs(f, schema, negated) if c.feasible()]
+
+
+def oracle_compile(f, schema, width=8):
+    conjs = _to_dnf(f, schema, False, max_width=width)
+    if len(conjs) > width:
+        raise ValueError(f"filter needs DNF width {len(conjs)} > {width}")
+    m_i = len(schema.int_columns)
+    m_f = len(schema.float_columns)
+    valid = np.zeros((width,), np.float32)
+    imask = np.zeros((width, m_i), np.uint32)
+    flo = np.full((width, m_f), np.inf, np.float32)
+    fhi = np.full((width, m_f), -np.inf, np.float32)
+    for w, c in enumerate(conjs):
+        valid[w] = 1.0
+        imask[w] = c.imask
+        flo[w] = c.flo
+        fhi[w] = c.fhi
+    return F.FilterProgram(valid, imask, flo, fhi)
+
+
+def _assert_matches_oracle(flts, schema, width):
+    """``compile_batch`` writes the oracle's stacked arrays bit for bit
+    (dtype, shape and row order), and raises what the oracle raises."""
+    try:
+        want = F.stack_programs([oracle_compile(f, schema, width) for f in flts])
+    except (ValueError, KeyError, TypeError) as e:
+        with pytest.raises(type(e)) as got:
+            F.compile_batch(flts, schema, width)
+        assert str(got.value) == str(e)
+        return
+    got = F.compile_batch(flts, schema, width)
+    assert list(got) == list(want)
+    for k in want:
+        assert got[k].dtype == want[k].dtype, k
+        assert got[k].shape == want[k].shape, k
+        assert got[k].tobytes() == want[k].tobytes(), k
+    for b, f in enumerate(flts[:8]):    # the one-program entry point too
+        one = F.compile_filter(f, schema, width)
+        for k in want:
+            assert getattr(one, k).tobytes() == want[k][b].tobytes(), k
+
+
+def _bench_batch(cell):
+    """The first 256 requests' filters of a benchmark cell's pool, drawn by
+    the benchmark's own generator from the cell's traffic file."""
+    bench = Path(__file__).resolve().parents[1] / "bench"
+    if str(bench) not in sys.path:
+        sys.path.append(str(bench))
+    import workload
+    c = workload.load_cell(cell)
+    pool = workload.make_pool(c.config, c.traffic, 2**31 + 4099)
+    flts = [workload.to_program_filter(pool.filters[i])
+            for i in pool.filter_of[:256]]
+    return flts, workload.program_schema(c.config), 8
+
+
+_FLOAT_ZERO_CASES = [
+    F.Not(F.Range("f0", 0.0, 5.0)), F.Not(F.Range("f0", -0.0, 5.0)),
+    F.Not(F.Range("f0", -3.0, 0.0)), F.Not(F.Range("f0", -3.0, -0.0)),
+    F.Not(F.Equality("f1", 0.0)), F.Not(F.Equality("f1", -0.0)),
+    F.And(F.Range("f0", -0.0, 5.0), F.Range("f0", 0.0, 3.0)),
+    F.And(F.Range("f0", 0.0, 5.0), F.Range("f0", -0.0, 3.0)),
+    F.And(F.Range("f0", -4.0, 0.0), F.Range("f0", -5.0, -0.0)),
+    F.And(F.Range("f0", -4.0, -0.0), F.Range("f0", -5.0, 0.0)),
+    F.And(F.Not(F.Range("f0", 0.0, 5.0)), F.Range("f0", -0.0, 9.0)),
+]
+_COLLAPSED = 1.0 + 1e-9       # rounds to float32 1.0
+_EDGE_CASES = {
+    "true_false": ([F.TrueFilter(), F.FalseFilter(), F.Not(F.TrueFilter()),
+                    F.Not(F.FalseFilter()), F.And(), F.Or(),
+                    F.And(F.TrueFilter(), F.FalseFilter()),
+                    F.Or(F.TrueFilter(), F.Equality("i0", 2))], SCHEMA, 8),
+    "signed_zero": (_FLOAT_ZERO_CASES, SCHEMA, 8),
+    "collapsed_range": ([F.Range("f0", 1.0, _COLLAPSED),
+                         F.Range("f0", _COLLAPSED, 1.0),
+                         F.Not(F.Range("f0", _COLLAPSED, 1.0)),
+                         F.And(F.Range("f0", 0.5, 1.0),
+                               F.Range("f0", _COLLAPSED, 2.0)),
+                         F.Inclusion("f1", [0.1, 0.1 + 1e-12, 7.0]),
+                         F.Range("i0", 2.5, 2.9), F.Range("i1", 3.0, 3.0)],
+                        SCHEMA, 8),
+    "width_guard": ([F.Equality("b0", True),
+                     F.And(*[F.Or(*[F.Equality(c, v) for v in (1, 2, 3)])
+                             for c in ("f0", "f1", "i0")])], SCHEMA, 4),
+    "width_overflow": ([F.Equality("b0", True),
+                        F.Inclusion("f1", [float(v) for v in range(5)])],
+                       SCHEMA, 4),
+    "or_guard": ([F.Or(*[F.Equality("f0", float(v)) for v in range(17)])],
+                 SCHEMA, 4),
+    "bad_value": ([F.Equality("i0", 3), F.Inclusion("i1", [2, 10])],
+                  SCHEMA, 8),
+    "not_float_inclusion": ([F.Not(F.Inclusion("f0", [1.0, 2.0]))],
+                            SCHEMA, 8),
+    "unknown_column": ([F.Range("nope", 0.0, 1.0)], SCHEMA, 8),
+    "empty_vocab": ([F.And(), F.TrueFilter(), F.Not(F.FalseFilter()),
+                     F.Or(F.And(), F.Range("f0", 1.0, 2.0)),
+                     F.And(F.Range("f0", 1.0, 2.0))],
+                    F.Schema((F.ColumnSpec("z", "int", 0),
+                              F.ColumnSpec("f0", "float"))), 8),
+    "no_float_columns": ([F.Equality("b0", True), F.Not(F.Inclusion("i0", [1, 2])),
+                          F.TrueFilter(), F.FalseFilter()],
+                         F.paper_schema(n_float=0), 8),
+    "no_int_columns": ([F.Range("f0", 1.0, 2.0), F.Not(F.Range("f0", 1.0, 2.0)),
+                        F.TrueFilter(), F.FalseFilter()],
+                       F.paper_schema(n_bool=0, n_int=0), 8),
+}
+
+
+@pytest.mark.parametrize("case", ["hypothesis", "sift128-pq.rare.closed",
+                                  "sift128-f32.paper.closed",
+                                  *_EDGE_CASES])
+def test_compile_batch_matches_oracle(case):
+    if case == "hypothesis":
+        @settings(max_examples=40, deadline=None)
+        @given(st.lists(filter_trees(), min_size=1, max_size=64),
+               st.sampled_from([4, 8, 16]))
+        def mixed(flts, width):
+            _assert_matches_oracle(flts, SCHEMA, width)
+        mixed()
+        return
+    flts, schema, width = (_EDGE_CASES[case] if case in _EDGE_CASES
+                           else _bench_batch(case))
+    _assert_matches_oracle(flts, schema, width)

@@ -546,6 +546,31 @@ def test_child_spans_recorded_under_their_paths(small_index, small_dataset):
     assert sum(v["count"] for v in series.values()) == n_spans
 
 
+def test_compile_span_splits_into_lower_and_upload(small_index,
+                                                  small_dataset):
+    """The compile stage opens ``lower`` (the batched filter compile on
+    the host) then ``upload`` (the programs' copy to the device), and keeps
+    its own attributes."""
+    _, _, schema = small_dataset
+    qs = _queries(6, 16, seed=3)
+    obs = Obs(ObsSpec(trace_sample=1.0, slow_ms=None),
+              time_fn=FakeClock(tick=0.001))
+    router.execute(LocalBackend(small_index), qs, _flt(schema), OPTS,
+                   obs=obs)
+    tr = obs.tracer.traces[-1]
+    comp = next(sp for sp in tr.spans if sp.name == "compile")
+    assert comp.attrs == {"rows": 6}
+    assert [c.name for c in comp.children] == ["lower", "upload"]
+    assert [c.path for c in comp.children] == ["compile/lower",
+                                               "compile/upload"]
+    for c in comp.children:
+        assert c.attrs == {} and not c.children
+        assert comp.t0 <= c.t0 and c.t1 <= comp.t1
+    hist = obs.registry.snapshot()["histograms"]["favor_stage_seconds"]
+    for path in ("compile", "compile/lower", "compile/upload"):
+        assert hist["series"][f'stage="{path}"']["count"] == 1, path
+
+
 def test_estimate_wait_fetch_and_slow_log_spans(small_index, small_dataset):
     _, _, schema = small_dataset
     eng = ServeEngine(LocalBackend(small_index), OPTS, max_batch=8,
