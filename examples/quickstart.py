@@ -1,8 +1,7 @@
 """Quickstart: build a FAVOR index and run hybrid vector+attribute queries.
 
 Uses the typed API: construction is configured by a frozen ``BuildSpec``,
-each search batch by a frozen ``SearchOptions`` (the legacy
-``fi.search(k=, ef=, ...)`` kwargs still work but are deprecated).
+each search batch by a frozen ``SearchOptions``.
 
     PYTHONPATH=src python examples/quickstart.py
 """

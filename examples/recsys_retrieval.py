@@ -1,72 +1,72 @@
-"""FAVOR as the recsys retrieval layer (the retrieval_cand cell, reduced).
+"""FAVOR as the recsys candidate-retrieval layer.
 
-Scores a user vector against a candidate item corpus under attribute filters
-(region/price/stock-style predicates), using:
-  1. the factorized dot-scoring path (jnp),
-  2. the FAVOR PreFBF Pallas kernel (fused filter + distance + top-k) via the
-     exact MIP->L2 augmentation reduction,
-  3. a FAVOR graph index over the item embeddings for sub-linear retrieval.
+Item and user embeddings are a seeded Gaussian mixture
+(``repro.data.synthetic``), L2-normalized so L2 ranking is cosine ranking.
+Each user asks for the nearest items under an attribute filter (stock,
+category and price predicates), served two ways:
+  1. the filtered brute route (PreFBF), jnp and the Pallas ``filtered_topk``
+     kernel side by side -- both exact, identical ids;
+  2. a ServeEngine over the FAVOR index, routing every user's request by its
+     estimated selectivity (graph or brute) in batches.
 
     PYTHONPATH=src python examples/recsys_retrieval.py
 """
 import time
 
-import jax.numpy as jnp
 import numpy as np
 
-from repro.core import (FavorIndex, HnswParams, SearchOptions,
-                        compile_filter, paper_schema, stack_programs)
+from repro.core import (FavorIndex, HnswParams, LocalBackend, SearchOptions,
+                        paper_schema, random_attributes, refimpl)
 from repro.core import filters as F
-from repro.core import random_attributes
-from repro.models.recsys import retrieval_topk_filtered
+from repro.data import synthetic
+from repro.serving import ServeEngine
+
+
+def normalize(x):
+    return x / np.linalg.norm(x, axis=1, keepdims=True)
 
 
 def main():
-    n_items, d, k = 20000, 32, 50
-    rng = np.random.default_rng(0)
-    items = rng.normal(size=(n_items, d)).astype(np.float32)
+    n_items, d, k, n_users = 10000, 32, 50, 16
+    items = normalize(synthetic.make_vector_dataset(n_items, d, seed=0))
+    users = normalize(synthetic.make_queries(n_users, d, dataset_seed=0))
     schema = paper_schema()        # b0 = in_stock, i0 = category, f0 = price
     attrs = random_attributes(schema, n_items, seed=1)
-    users = rng.normal(size=(4, d)).astype(np.float32)
+    fi = FavorIndex.build(items, attrs, HnswParams(M=12, efc=60, seed=2))
 
     flt = F.And(F.Equality("b0", True),          # in stock
                 F.Inclusion("i0", [2, 5, 7]),    # category in {2,5,7}
                 F.Range("f0", 10.0, 80.0))       # price band
-    progs = {kk: jnp.asarray(v) for kk, v in stack_programs(
-        [compile_filter(flt, schema)] * len(users)).items()}
-    ai, af = jnp.asarray(attrs.ints), jnp.asarray(attrs.floats)
-    it, uv = jnp.asarray(items), jnp.asarray(users)
+    mask = F.eval_program(F.compile_filter(flt, schema), attrs.ints,
+                          attrs.floats)
+    truth = [refimpl.bruteforce_filtered(items, mask, u, k)[0] for u in users]
 
-    t0 = time.perf_counter()
-    ids_j, sc_j = retrieval_topk_filtered(uv, it, progs, ai, af, k=k)
-    ids_j.block_until_ready()
-    print(f"jnp dot-scoring path:    {time.perf_counter()-t0:.3f}s "
-          f"(top score {float(sc_j[0, 0]):.3f})")
+    def recall(ids):
+        return np.mean([refimpl.recall_at_k(ids[i], truth[i], k)
+                        for i in range(n_users)])
 
-    t0 = time.perf_counter()
-    ids_p, sc_p = retrieval_topk_filtered(uv, it, progs, ai, af, k=k,
-                                          use_pallas=True)
-    ids_p.block_until_ready()
-    print(f"Pallas filtered_topk:    {time.perf_counter()-t0:.3f}s "
-          f"(interpret mode on CPU; identical ids: "
-          f"{bool((ids_j == ids_p).all())})")
+    brute = SearchOptions(k=k, force="brute")
+    brute_ids = []
+    for name, opts in (("jnp brute scan", brute),
+                       ("Pallas filtered_topk", brute.with_(use_pallas=True))):
+        t0 = time.perf_counter()
+        res = fi.query(users, flt, opts)
+        print(f"{name:22s} {time.perf_counter() - t0:.3f}s "
+              f"recall@{k}={recall(res.ids):.3f}")
+        brute_ids.append(res.ids)
+    print(f"  (Pallas in interpret mode on CPU) identical ids: "
+          f"{bool((brute_ids[0] == brute_ids[1]).all())}")
 
-    # graph path: L2 FAVOR index over L2-normalized items (cosine retrieval)
-    items_n = items / np.linalg.norm(items, axis=1, keepdims=True)
-    fi = FavorIndex.build(items_n, attrs, HnswParams(M=12, efc=60, seed=2))
-    users_n = users / np.linalg.norm(users, axis=1, keepdims=True)
     # at p ~= 10% the result pool must reach ~k/p neighbors: ef >> 2k
-    res = fi.query(users_n, flt, SearchOptions(k=k, ef=8 * k))
-    overlap = []
-    # cosine ground truth under the same filter
-    from repro.core import refimpl
-    mask = F.eval_program(compile_filter(flt, schema), attrs.ints, attrs.floats)
-    for i in range(len(users)):
-        truth, _ = refimpl.bruteforce_filtered(items_n, mask, users_n[i], k)
-        overlap.append(refimpl.recall_at_k(res.ids[i], truth, k))
-    print(f"FAVOR graph retrieval:   recall@{k}={np.mean(overlap):.3f} "
-          f"qps={res.qps:.1f} (p_hat={res.p_hat[0]:.3f}, "
-          f"route={'brute' if res.routed_brute[0] else 'graph'})")
+    eng = ServeEngine(LocalBackend(fi), SearchOptions(k=k, ef=8 * k),
+                      max_batch=8)
+    for u in users:
+        eng.submit(u, flt)
+    out = sorted(eng.run(), key=lambda r: r.rid)
+    ids = np.stack([r.ids for r in out])
+    print(f"ServeEngine:            recall@{k}={recall(ids):.3f} "
+          f"(p_hat={out[0].p_hat:.3f}, routes={eng.stats['graph']} graph "
+          f"/ {eng.stats['brute']} brute)")
 
 
 if __name__ == "__main__":

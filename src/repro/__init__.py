@@ -1,6 +1,3 @@
-"""repro: FAVOR (filter-agnostic vector ANNS) as a production JAX framework.
-
-NOTE: this module must stay import-light (no jax import here) so that
-launch/dryrun.py can set XLA_FLAGS before jax initializes.
-"""
+"""repro: FAVOR (filter-agnostic vector ANNS) as a filtered vector-search
+service in JAX/Pallas."""
 __version__ = "1.1.0"

@@ -3,8 +3,8 @@
 A cold run compiles every bucket's estimate, graph and brute executables
 and then the Pallas kernels; the persistent cache lets the next process on
 the same machine skip that.  Library code never calls this (importing
-``repro`` must not touch global JAX state); ``chip_smoke.py`` and
-``benchmarks/run.py`` call it before their first compile.
+``repro`` must not touch global JAX state); ``chip_smoke.py`` calls it
+before its first compile.
 """
 from __future__ import annotations
 

@@ -11,8 +11,8 @@ Online :  compile each query's filter to a DNF program, estimate p_hat on the
 The online pipeline itself lives in router.execute (shared with the serving
 engine and the sharded backend); this class owns offline state -- device
 arrays, selectivity sample, optional PQ/SQ codes -- and exposes it through a
-LocalBackend.  ``query(queries, filters, SearchOptions(...))`` is the typed
-API; ``search(**kwargs)`` remains as a deprecated shim over it.
+LocalBackend.  ``query(queries, filters, SearchOptions(...))`` is the
+search API.
 """
 from __future__ import annotations
 
@@ -25,18 +25,15 @@ import jax.numpy as jnp
 import numpy as np
 
 from . import filters as F
-from . import prefbf, selectivity, selector
+from . import prefbf, selectivity
 from .hnsw import HnswIndex, HnswParams, build_hnsw
 from .options import BuildSpec, QuantSpec, SearchOptions
-from .router import SearchResult, compile_programs, execute
+from .router import SearchResult, execute
 from .search import graph_arrays, refresh_graph_arrays
 from ..index.epochs import ComponentEpochs
 from ..index.live import LiveState
 
 __all__ = ["FavorIndex", "SearchResult"]
-
-_LEGACY_BUILD_KW = ("sel_cfg", "prefbf_chunk", "quantize", "pq_m", "pq_nbits",
-                    "pq_train_iters", "pq_train_sample", "rerank")
 
 
 @dataclass
@@ -57,26 +54,6 @@ class _MergePrep:
     g: dict
 
 
-def _spec_from_legacy(kw: dict) -> BuildSpec:
-    """Map the pre-BuildSpec __init__ kwargs onto a BuildSpec."""
-    quant = None
-    if kw.get("quantize") is not None:
-        quant = QuantSpec(
-            kind=kw["quantize"],
-            m=kw.get("pq_m") if kw.get("pq_m") is not None else 8,
-            nbits=kw.get("pq_nbits") if kw.get("pq_nbits") is not None else 8,
-            train_iters=(kw.get("pq_train_iters")
-                         if kw.get("pq_train_iters") is not None else 20),
-            train_sample=(kw.get("pq_train_sample")
-                          if kw.get("pq_train_sample") is not None else 65536),
-            rerank=kw.get("rerank") if kw.get("rerank") is not None else 4)
-    return BuildSpec(
-        selector=kw.get("sel_cfg") or selector.SelectorConfig(),
-        prefbf_chunk=(kw.get("prefbf_chunk")
-                      if kw.get("prefbf_chunk") is not None else 8192),
-        quant=quant)
-
-
 class FavorIndex:
     """Single-host FAVOR index.  Execution goes through a LocalBackend; the
     multi-device variant is core.backend.ShardedBackend behind the same
@@ -84,28 +61,10 @@ class FavorIndex:
 
     def __init__(self, index: HnswIndex, attrs: F.AttributeTable,
                  spec: BuildSpec | None = None, *, codebook=None,
-                 codes=None, **legacy):
-        if isinstance(spec, selector.SelectorConfig):
-            # pre-1.1 third positional was sel_cfg
-            if legacy.get("sel_cfg") is not None:
-                raise ValueError("sel_cfg passed both positionally and by "
-                                 "keyword")
-            legacy["sel_cfg"], spec = spec, None
-        elif spec is not None and not isinstance(spec, BuildSpec):
+                 codes=None):
+        if spec is not None and not isinstance(spec, BuildSpec):
             raise TypeError("spec must be a BuildSpec, got "
                             f"{type(spec).__name__}")
-        unknown = set(legacy) - set(_LEGACY_BUILD_KW)
-        if unknown:
-            raise TypeError(f"unexpected FavorIndex kwargs: {sorted(unknown)}")
-        if legacy and any(v is not None for v in legacy.values()):
-            if spec is not None:
-                raise ValueError("pass either spec=BuildSpec(...) or legacy "
-                                 "kwargs, not both")
-            warnings.warn(
-                "FavorIndex(sel_cfg=/quantize=/pq_*=/rerank=...) is "
-                "deprecated; pass spec=BuildSpec(...)",
-                DeprecationWarning, stacklevel=2)
-            spec = _spec_from_legacy(legacy)
         if spec is None:
             spec = BuildSpec()
         # an externally trained/loaded codebook implies its quant kind AND
@@ -114,13 +73,10 @@ class FavorIndex:
         # ShardedBackend.build parity)
         if spec.quant is None and codebook is not None:
             from ..quant import PQCodebook
-            rr = legacy.get("rerank")
-            rr = rr if rr is not None else 4
             if isinstance(codebook, PQCodebook):
-                q = QuantSpec(kind="pq", m=codebook.m, nbits=codebook.nbits,
-                              rerank=rr)
+                q = QuantSpec(kind="pq", m=codebook.m, nbits=codebook.nbits)
             else:
-                q = QuantSpec(kind="sq", rerank=rr)
+                q = QuantSpec(kind="sq")
             spec = BuildSpec(hnsw=spec.hnsw, selector=spec.selector,
                              prefbf_chunk=spec.prefbf_chunk, quant=q)
 
@@ -464,35 +420,12 @@ class FavorIndex:
             self._backend = LocalBackend(self)
         return self._backend
 
-    def compile_filters(self, filters, width: int = 8) -> dict:
-        if isinstance(filters, F.Filter):
-            filters = [filters]
-        return compile_programs(filters, self.schema, len(filters), width)
-
     # -- online search --------------------------------------------------------
     def query(self, queries: np.ndarray, filters,
               opts: SearchOptions | None = None) -> SearchResult:
         """Typed search API: one SearchOptions drives routing + execution
         (shared router; identical on every backend)."""
         return execute(self.backend, queries, filters, opts or SearchOptions())
-
-    def search(self, queries: np.ndarray, filters, k: int = 10, ef: int = 100,
-               *, pbar_min: float = 0.5, gamma: float = 1.0,
-               force: str | None = None, use_pallas: bool = False,
-               cand_cap: int = 0, use_pq: bool = False,
-               rerank: int | None = None) -> SearchResult:
-        """Deprecated kwarg shim over ``query``; kept so pre-SearchOptions
-        callers run unmodified.  ``rerank=0`` is honored (re-rank exactly the
-        top k) -- it is no longer swallowed by a falsy-or default."""
-        warnings.warn(
-            "FavorIndex.search(k=, ef=, ...) is deprecated; use "
-            "FavorIndex.query(queries, filters, SearchOptions(...))",
-            DeprecationWarning, stacklevel=2)
-        opts = SearchOptions(k=k, ef=ef, pbar_min=pbar_min, gamma=gamma,
-                             force=force, cand_cap=cand_cap,
-                             use_pallas=use_pallas, use_pq=use_pq,
-                             rerank=rerank)
-        return self.query(queries, filters, opts)
 
     def bytes_per_vector(self, quantized: bool = False) -> int:
         """Bytes streamed per DB row by the brute scan (float32 vs codes)."""

@@ -74,12 +74,6 @@ class HnswIndex:
         row = self.levels[level][node]
         return row[row >= 0]
 
-    def storage_bytes(self) -> int:
-        b = self.vectors.nbytes + self.node_level.nbytes
-        for lv in self.levels:
-            b += lv.nbytes
-        return b
-
     def save(self, path: str, quant: dict | None = None) -> None:
         """Persist the index; ``quant`` (or ``self.quant_state``) rides along
         under ``quant_*`` keys so a reloaded index can serve the compressed

@@ -8,11 +8,9 @@ Each kernel package ships three files:
 
 Kernels:
   filtered_topk   -- fused L2 distance + filter-program mask + exclusion
-                     distance + running top-k (PreFBF / retrieval_cand path)
+                     distance + running top-k (the PreFBF brute route)
   gather_distance -- scalar-prefetch neighbor gather + distance + exclusion
                      (graph-search expansion; paged-attention indirection idiom)
-  embedding_bag   -- scalar-prefetch row gather + segment-sum bag reduce
-                     (recsys embedding lookup; JAX has no native EmbeddingBag)
   pq_adc          -- fused PQ asymmetric-distance LUT accumulate + filter
                      mask + running top-R over uint8 code chunks (the
                      compressed PreFBF scan; quant/adc.py re-ranks exactly)

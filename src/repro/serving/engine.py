@@ -15,14 +15,12 @@ frozen ``SearchOptions``:
     eng = ServeEngine(LocalBackend(fi), SearchOptions(k=10, ef=96))
     eng = ServeEngine(ShardedBackend.build(vecs, attrs, mesh, spec), opts)
 
-Passing a FavorIndex (optionally with the legacy k=/ef=/use_pq= kwargs)
-still works and wraps it in a LocalBackend.
+Passing a FavorIndex wraps it in a LocalBackend.
 """
 from __future__ import annotations
 
 import threading
 import time
-import warnings
 from collections import deque
 from contextlib import contextmanager
 from dataclasses import dataclass, field
@@ -101,29 +99,12 @@ class ServeEngine:
                  merge_delta_frac: float | None = None,
                  merge_background: bool = False,
                  obs: "Obs | ObsSpec | None" = None,
-                 time_fn=time.perf_counter,
-                 k: int | None = None, ef: int | None = None,
-                 use_pq: bool | None = None):
+                 time_fn=time.perf_counter):
         if isinstance(backend, FavorIndex):
             backend = LocalBackend(backend)
-        if isinstance(opts, int) and not isinstance(opts, bool):
-            # pre-1.1 second positional was k: ServeEngine(fi, 10)
-            if k is not None:
-                raise ValueError("k passed both positionally and by keyword")
-            k, opts = opts, None
         if opts is not None and not isinstance(opts, SearchOptions):
             raise TypeError("opts must be a SearchOptions, got "
                             f"{type(opts).__name__}")
-        if k is not None or ef is not None or use_pq is not None:
-            if opts is not None:
-                raise ValueError("pass either opts=SearchOptions(...) or "
-                                 "legacy k=/ef=/use_pq= kwargs, not both")
-            warnings.warn(
-                "ServeEngine(k=, ef=, use_pq=) is deprecated; pass "
-                "SearchOptions(...)", DeprecationWarning, stacklevel=2)
-            opts = SearchOptions(k=k if k is not None else 10,
-                                 ef=ef if ef is not None else 100,
-                                 use_pq=bool(use_pq))
         self.backend = backend
         self.opts = opts or SearchOptions()
         # incompatible (backend, opts) pairs fail here, not mid-serve

@@ -1,4 +1,4 @@
-"""Unified search API: SearchOptions validation, legacy shims, router
+"""Unified search API: SearchOptions validation, router
 routing parity between LocalBackend and ShardedBackend, ServeEngine over
 both backends (the sharded 2-device run lives in a subprocess)."""
 import os
@@ -42,6 +42,22 @@ def test_quant_spec_validation():
         BuildSpec(prefbf_chunk=0)
 
 
+def test_constructors_take_only_typed_specs(small_index, small_dataset):
+    """FavorIndex takes a BuildSpec and ServeEngine a SearchOptions; any
+    other third or second positional is a TypeError, not a guess."""
+    from repro.core.selector import SelectorConfig
+    _, attrs, _ = small_dataset
+    for bad in ({"quant": None}, SelectorConfig(lam=0.02)):
+        with pytest.raises(TypeError, match="BuildSpec"):
+            FavorIndex(small_index.index, attrs, bad)
+    for bad in ({"k": 5}, 5):
+        with pytest.raises(TypeError, match="SearchOptions"):
+            ServeEngine(small_index, bad)
+    eng = ServeEngine(small_index, SearchOptions(k=5, ef=48), max_batch=8)
+    assert isinstance(eng.backend, LocalBackend)
+    assert eng.opts == SearchOptions(k=5, ef=48)
+
+
 def test_plan_routes_force_and_threshold():
     p = np.array([0.001, 0.5])
     plan = router.plan_routes(p, lam=0.01)
@@ -58,50 +74,6 @@ def test_filter_count_mismatch_is_value_error(small_index, small_dataset):
     flt = paper_filters(schema)["equality_bool"]
     with pytest.raises(ValueError, match="one filter per query"):
         small_index.query(qs, [flt] * 3, SearchOptions(k=5, ef=48))
-
-
-# ---------------------------------------------------------------------------
-# legacy shims
-# ---------------------------------------------------------------------------
-def test_legacy_search_kwargs_warn_and_match(small_index, small_dataset):
-    vecs, _, schema = small_dataset
-    rng = np.random.default_rng(31)
-    qs = rng.normal(size=(6, vecs.shape[1])).astype(np.float32)
-    flt = paper_filters(schema)["equality_bool"]
-    with pytest.deprecated_call():
-        legacy = small_index.search(qs, flt, k=5, ef=48)
-    typed = small_index.query(qs, flt, SearchOptions(k=5, ef=48))
-    np.testing.assert_array_equal(legacy.ids, typed.ids)
-    np.testing.assert_array_equal(legacy.routed_brute, typed.routed_brute)
-
-
-def test_legacy_build_kwargs_warn(small_index, small_dataset):
-    vecs, attrs, _ = small_dataset
-    with pytest.deprecated_call():
-        fi = FavorIndex(small_index.index, attrs, quantize="sq", rerank=2)
-    assert fi.quantize == "sq" and fi.rerank == 2
-    assert fi.spec.quant == QuantSpec(kind="sq", rerank=2)
-    # pre-1.1 third positional was sel_cfg
-    from repro.core.selector import SelectorConfig
-    with pytest.deprecated_call():
-        fi = FavorIndex(small_index.index, attrs, SelectorConfig(lam=0.02))
-    assert fi.sel_cfg.lam == 0.02
-    with pytest.raises(TypeError, match="BuildSpec"):
-        FavorIndex(small_index.index, attrs, {"quant": None})
-
-
-def test_legacy_engine_kwargs_warn(small_index):
-    with pytest.deprecated_call():
-        eng = ServeEngine(small_index, k=5, ef=48, max_batch=8)
-    assert eng.opts == SearchOptions(k=5, ef=48)
-    with pytest.raises(ValueError, match="not both"):
-        ServeEngine(small_index, SearchOptions(), k=5)
-    # pre-1.1 second positional was k
-    with pytest.deprecated_call():
-        eng = ServeEngine(small_index, 5)
-    assert eng.opts.k == 5
-    with pytest.raises(TypeError, match="SearchOptions"):
-        ServeEngine(small_index, {"k": 5})
 
 
 def test_loaded_codebook_round_trips_quant_spec(small_index, small_dataset,

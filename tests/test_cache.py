@@ -208,22 +208,35 @@ def test_epoch_bump_invalidates_all_layers(cached_local, small_index,
     np.testing.assert_array_equal(r0.ids, r1.ids)
 
 
-def test_semantic_threshold_serves_near_duplicates(small_index, small_dataset):
+@pytest.mark.parametrize("threshold", [0.5, 0.0])
+def test_semantic_threshold_serves_near_duplicates(small_index, small_dataset,
+                                                   threshold):
+    """Threshold 0 serves exact repeats only; a larger radius serves the
+    same stream's near duplicates too, never fewer hits."""
     vecs, _, schema = small_dataset
     cb = CachingBackend(LocalBackend(small_index),
-                        CacheSpec(semantic_threshold=0.5, candidates=False))
+                        CacheSpec(semantic_threshold=threshold,
+                                  candidates=False))
     flt = paper_filters(schema)["equality_bool"]
     opts = SearchOptions(k=5, ef=48)
     rng = np.random.default_rng(54)
     q = rng.normal(size=(1, vecs.shape[1])).astype(np.float32)
     r0 = router.execute(cb, q, flt, opts)
     jitter = q + (0.1 / np.sqrt(vecs.shape[1])).astype(np.float32)
-    r1 = router.execute(cb, jitter, flt, opts)     # within threshold
-    np.testing.assert_array_equal(r0.ids, r1.ids)  # served from cache
-    assert cb.cache_stats()["semantic"]["hits"] == 1
+    r1 = router.execute(cb, jitter, flt, opts)
+    if threshold:                                  # within threshold
+        np.testing.assert_array_equal(r0.ids, r1.ids)  # served from cache
+        assert cb.cache_stats()["semantic"]["hits"] == 1
+    else:                                          # not a repeat: a miss
+        assert cb.cache_stats()["semantic"]["hits"] == 0
+        np.testing.assert_array_equal(
+            r1.ids, router.execute(LocalBackend(small_index), jitter, flt,
+                                   opts).ids)
     far = q + 10.0
     router.execute(cb, far, flt, opts)             # outside threshold: miss
     assert cb.cache_stats()["semantic"]["misses"] >= 2
+    router.execute(cb, q, flt, opts)               # an exact repeat: a hit
+    assert cb.cache_stats()["semantic"]["hits"] == 1 + (threshold > 0)
 
 
 def test_disabled_layers_bypass(small_index, small_dataset):

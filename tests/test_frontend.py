@@ -155,6 +155,60 @@ def test_drain_forces_immediately(small_index, small_dataset):
     assert len(out) == 1 and not eng.queue
 
 
+def test_serve_engine(small_index, small_dataset):
+    vecs, attrs, schema = small_dataset
+    eng = ServeEngine(LocalBackend(small_index), SearchOptions(k=5, ef=48),
+                      max_batch=16)
+    flts = F.paper_filters(schema)
+    rng = np.random.default_rng(0)
+    rids = []
+    for i in range(40):
+        q = rng.normal(size=(vecs.shape[1],)).astype(np.float32)
+        name = list(flts)[i % len(flts)]
+        rids.append(eng.submit(q, flts[name]))
+    out = eng.run()
+    assert len(out) == 40
+    assert sorted(r.rid for r in out) == sorted(rids)
+    assert eng.stats["graph"] + eng.stats["brute"] == 40
+    pct = eng.latency_percentiles()
+    assert pct["p50"] <= pct["p99"]
+
+
+def test_serve_engine_deadline(small_index, small_dataset):
+    """max_wait_ms is honored: a partial batch waits for the deadline, a full
+    batch flushes immediately, and run(until_empty=) is wired."""
+    vecs, attrs, schema = small_dataset
+    eng = ServeEngine(LocalBackend(small_index), SearchOptions(k=5, ef=48),
+                      max_batch=8, max_wait_ms=1e6)
+    flts = list(F.paper_filters(schema).values())
+    rng = np.random.default_rng(1)
+
+    def submit(n):
+        for i in range(n):
+            q = rng.normal(size=(vecs.shape[1],)).astype(np.float32)
+            eng.submit(q, flts[i % len(flts)])
+
+    # partial batch, deadline far in the future -> engine keeps waiting
+    submit(3)
+    assert eng.step() == []
+    assert eng.run(until_empty=False) == []
+    assert len(eng.queue) == 3
+
+    # oldest request past the deadline -> the partial batch flushes
+    eng.queue[0].t_submit -= 2 * eng.max_wait_s
+    out = eng.step()
+    assert len(out) == 3 and not eng.queue
+
+    # full batch flushes immediately despite the huge deadline
+    submit(8)
+    assert len(eng.step()) == 8
+
+    # drain() forces out partial batches immediately, even with the huge
+    # deadline (run(until_empty=True) would wait the straggler window out)
+    submit(3)
+    assert len(eng.drain()) == 3 and not eng.queue
+
+
 # ---------------------------------------------------------------------------
 # Front-end: coalescing parity + pad reduction
 # ---------------------------------------------------------------------------
@@ -217,7 +271,10 @@ def test_coalescing_cuts_pad_overhead(small_index, small_dataset):
 # ---------------------------------------------------------------------------
 # Admission control: shed at the door, never the backend
 # ---------------------------------------------------------------------------
-def test_shed_requests_never_reach_backend(small_index, small_dataset):
+@pytest.mark.parametrize("cold", [False, True])
+def test_shed_requests_never_reach_backend(small_index, small_dataset, cold):
+    """``cold``: a second tenant submits while the first is being shed; its
+    request is served and no shed is charged to it."""
     _, _, schema = small_dataset
     flt = _flt(schema)
     qs = _queries(4, 16, seed=13)
@@ -225,26 +282,34 @@ def test_shed_requests_never_reach_backend(small_index, small_dataset):
     async def main():
         eng = ServeEngine(LocalBackend(small_index), OPTS, max_batch=16)
         spec = FrontEndSpec(coalesce_ms=1e4, coalesce_target=64,
-                            tenants={"t": TenantSpec(queue_cap=1)})
+                            tenants={"t": TenantSpec(queue_cap=1),
+                                     "c": TenantSpec(queue_cap=1)})
         fe = FrontEnd(eng, spec)
         t1 = asyncio.create_task(fe.submit(qs[0], flt, tenant="t"))
+        tc = (asyncio.create_task(fe.submit(qs[3], flt, tenant="c"))
+              if cold else None)
         await asyncio.sleep(0.02)             # t1 is queued (held window)
         shed = []
         for i in (1, 2):
             with pytest.raises(Overloaded) as e:
                 await fe.submit(qs[i], flt, tenant="t")
             shed.append(e.value.reason)
-        await fe.close(drain=True)            # serves only the queued one
-        return await t1, shed, fe.stats
+        await fe.close(drain=True)            # serves only the queued ones
+        rc = await tc if cold else None
+        return await t1, rc, shed, fe.stats
 
-    r1, shed, st = asyncio.run(main())
+    r1, rc, shed, st = asyncio.run(main())
     assert shed == ["queue_full", "queue_full"]
     t = st["tenants"]["t"]
     assert t["served"] == 1 and t["shed"]["queue_full"] == 2
     assert t["shed_total"] == 2
-    # the backend saw exactly the served request, nothing shed
-    assert st["engine"]["graph"] + st["engine"]["brute"] == 1
+    # the backend saw exactly the served requests, nothing shed
+    assert st["engine"]["graph"] + st["engine"]["brute"] == 1 + cold
     assert r1.ids.shape == (5,)
+    if cold:
+        c = st["tenants"]["c"]
+        assert c["served"] == 1 and c["shed_total"] == 0
+        assert rc.ids.shape == (5,)
 
 
 def test_rate_limit_shed_with_retry_after(small_index, small_dataset):

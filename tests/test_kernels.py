@@ -5,8 +5,6 @@ import pytest
 
 from repro.core import compile_filter, paper_schema, random_attributes, stack_programs
 from repro.core import filters as F
-from repro.kernels.embedding_bag import ops as eb_ops
-from repro.kernels.embedding_bag import ref as eb_ref
 from repro.kernels.filtered_topk import ops as ft_ops
 from repro.kernels.filtered_topk import ref as ft_ref
 from repro.kernels.gather_distance import ops as gd_ops
@@ -219,31 +217,3 @@ def test_pq_adc_gather_all_padded():
     assert out.shape == (b, m0)
     assert np.isinf(out).all()
 
-
-# ---------------------------------------------------------------------------
-# embedding_bag
-# ---------------------------------------------------------------------------
-@pytest.mark.parametrize("v,d,b,l,mode", [
-    (100, 16, 8, 4, "sum"), (100, 16, 8, 4, "mean"),
-    (1000, 32, 4, 10, "sum"), (50, 8, 16, 1, "mean"),
-    (257, 64, 3, 7, "sum"),
-])
-def test_embedding_bag_sweep(v, d, b, l, mode):
-    rng = np.random.default_rng(v + l)
-    table = jnp.asarray(rng.normal(size=(v, d)).astype(np.float32))
-    bags = rng.integers(0, v, size=(b, l)).astype(np.int32)
-    # random -1 padding tail per bag
-    for i in range(b):
-        cut = rng.integers(1, l + 1)
-        bags[i, cut:] = -1
-    out = eb_ops.embedding_bag(table, jnp.asarray(bags), mode=mode)
-    ref = eb_ref.embedding_bag_ref(jnp.asarray(bags), table, mode=mode)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                               rtol=1e-5, atol=1e-5)
-
-
-def test_embedding_bag_all_padding():
-    table = jnp.ones((10, 4), jnp.float32)
-    bags = jnp.full((2, 3), -1, jnp.int32)
-    out = eb_ops.embedding_bag(table, bags, mode="mean")
-    np.testing.assert_allclose(np.asarray(out), 0.0)

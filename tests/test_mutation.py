@@ -548,10 +548,13 @@ def test_save_warns_on_unmerged_mutations(tmp_path, ds):
 # ---------------------------------------------------------------------------
 # ServeEngine mutation API + merge scheduling
 # ---------------------------------------------------------------------------
-def test_engine_mutation_stats_and_auto_merge(ds):
+@pytest.mark.parametrize("merge_delta_frac", [0.01, None])
+def test_engine_mutation_stats_and_auto_merge(ds, merge_delta_frac):
+    """With ``merge_delta_frac`` None nothing merges: the live delta holds
+    every upserted row less the deleted one, and serves them."""
     vecs, attrs, schema = ds
     eng = ServeEngine(_fresh_local(ds), SearchOptions(k=5, ef=48),
-                      merge_delta_frac=0.01)
+                      merge_delta_frac=merge_delta_frac)
     n = vecs.shape[0]
     rng = np.random.default_rng(89)
     ints, floats = _matching_attrs(attrs, schema, count=9)
@@ -565,6 +568,14 @@ def test_engine_mutation_stats_and_auto_merge(ds):
     assert len(out) == 3
     st = eng.stats["mutations"]
     assert st["upserts"] == 9 and st["deletes"] == 1
+    if merge_delta_frac is None:
+        assert st["auto_merges"] == 0
+        assert st["delta_rows"] == 8 and st["delta_slots"] == 9
+        assert st["base_rows"] == n
+        q = eng.backend.index.live.delta.vectors[1].astype(np.float32)
+        eng.submit(q, flt)
+        assert int(ids[1]) in eng.run()[0].ids   # served from the delta
+        return
     assert st["auto_merges"] == 1           # 9/768 > 1% -> merged post-step
     assert st["delta_rows"] == 0 and st["base_rows"] == n + 9
     # post-merge serving still finds a surviving upserted row

@@ -3,8 +3,9 @@ import numpy as np
 import jax.numpy as jnp
 import pytest
 
-from repro.core import (FavorIndex, compile_filter, paper_filters,
-                        paper_schema, random_attributes, stack_programs)
+from repro.core import (BuildSpec, FavorIndex, QuantSpec, SearchOptions,
+                        compile_filter, paper_filters, paper_schema,
+                        random_attributes, stack_programs)
 from repro.core import filters as F
 from repro.core import refimpl
 from repro.kernels.pq_adc import ops as pq_ops
@@ -139,15 +140,15 @@ def test_pq_adc_kernel_matches_jnp_scan():
 @pytest.fixture(scope="module")
 def quant_index(small_index, small_dataset):
     vecs, attrs, _ = small_dataset
-    return FavorIndex(small_index.index, attrs, quantize="pq", pq_m=8,
-                      pq_nbits=6, pq_train_iters=15, rerank=4)
+    return FavorIndex(small_index.index, attrs, BuildSpec(quant=QuantSpec(
+        kind="pq", m=8, nbits=6, train_iters=15, rerank=4)))
 
 
 def test_use_pq_requires_quantized_index(small_index, small_dataset):
     vecs, _, schema = small_dataset
     qs = np.zeros((2, vecs.shape[1]), np.float32)
     with pytest.raises(ValueError, match="quantize"):
-        small_index.search(qs, F.TrueFilter(), k=5, use_pq=True)
+        small_index.query(qs, F.TrueFilter(), SearchOptions(k=5, use_pq=True))
 
 
 def test_e2e_pq_recall_within_2pts(quant_index, small_dataset):
@@ -158,8 +159,9 @@ def test_e2e_pq_recall_within_2pts(quant_index, small_dataset):
         mask = F.eval_program(compile_filter(flt, schema), attrs.ints,
                               attrs.floats)
         truth = [refimpl.bruteforce_filtered(vecs, mask, q, 10)[0] for q in qs]
-        r_f32 = quant_index.search(qs, flt, k=10, force="brute")
-        r_pq = quant_index.search(qs, flt, k=10, force="brute", use_pq=True)
+        r_f32 = quant_index.query(qs, flt, SearchOptions(k=10, force="brute"))
+        r_pq = quant_index.query(qs, flt, SearchOptions(k=10, force="brute",
+                                                        use_pq=True))
         rec_f32 = np.mean([refimpl.recall_at_k(i[i >= 0], t, 10)
                            for i, t in zip(r_f32.ids, truth)])
         rec_pq = np.mean([refimpl.recall_at_k(i[i >= 0], t, 10)
@@ -175,20 +177,21 @@ def test_e2e_pq_routed_search(quant_index, small_dataset):
     rng = np.random.default_rng(22)
     qs = rng.normal(size=(8, vecs.shape[1])).astype(np.float32)
     flt = paper_filters(schema)["range_50"]
-    res = quant_index.search(qs, flt, k=10, use_pq=True)
+    res = quant_index.query(qs, flt, SearchOptions(k=10, use_pq=True))
     assert np.all(np.sort(res.dists, axis=1) == res.dists)
     assert res.ids.shape == (8, 10)
 
 
 def test_sq_fallback_e2e(small_index, small_dataset):
     vecs, attrs, schema = small_dataset
-    fi = FavorIndex(small_index.index, attrs, quantize="sq", rerank=4)
+    fi = FavorIndex(small_index.index, attrs,
+                    BuildSpec(quant=QuantSpec(kind="sq", rerank=4)))
     assert fi.bytes_per_vector(quantized=True) == vecs.shape[1]
     rng = np.random.default_rng(23)
     qs = rng.normal(size=(6, vecs.shape[1])).astype(np.float32)
     flt = paper_filters(schema)["equality_bool"]
-    r_f32 = small_index.search(qs, flt, k=10, force="brute")
-    r_sq = fi.search(qs, flt, k=10, force="brute", use_pq=True)
+    r_f32 = small_index.query(qs, flt, SearchOptions(k=10, force="brute"))
+    r_sq = fi.query(qs, flt, SearchOptions(k=10, force="brute", use_pq=True))
     # int8 scalar quantization + 4x re-rank recovers the exact top-10 here
     assert (r_sq.ids == r_f32.ids).mean() > 0.95
 
@@ -205,6 +208,7 @@ def test_index_save_load_roundtrip_with_codebook(quant_index, small_dataset,
     rng = np.random.default_rng(24)
     qs = rng.normal(size=(4, vecs.shape[1])).astype(np.float32)
     flt = paper_filters(schema)["inclusion"]
-    r1 = quant_index.search(qs, flt, k=10, force="brute", use_pq=True)
-    r2 = fi2.search(qs, flt, k=10, force="brute", use_pq=True)
+    opts = SearchOptions(k=10, force="brute", use_pq=True)
+    r1 = quant_index.query(qs, flt, opts)
+    r2 = fi2.query(qs, flt, opts)
     np.testing.assert_array_equal(r1.ids, r2.ids)

@@ -8,9 +8,9 @@ import pytest
 from repro.core import (BuildSpec, ExactScorer, FavorIndex, HnswParams,
                         LocalBackend, PqAdcScorer, QuantSpec, Scorer,
                         SearchConfig, SearchOptions, SqScorer, compile_filter,
-                        exclusion_compose, graph_arrays, paper_filters,
-                        paper_schema, random_attributes, router,
-                        rsf_graph_search, scorer_for, stack_programs)
+                        exclusion_compose, favor_graph_search, graph_arrays,
+                        paper_filters, paper_schema, random_attributes,
+                        router, rsf_graph_search, scorer_for, stack_programs)
 from repro.core import filters as F
 from repro.core import refimpl
 from repro.serving import ServeEngine
@@ -188,9 +188,13 @@ def test_pq_graph_recall_within_1pt_of_f32(pq_corpus, scenario):
     be = LocalBackend(fi)
     rec = {}
     for gq in (None, "pq"):
+        compiled = favor_graph_search._cache_size()
         res = router.execute(be, queries, flt,
                              SearchOptions(k=10, ef=96, force="graph",
                                            graph_quant=gq))
+        # the lane-compaction ladder runs inside one executable per
+        # (scorer, program shape): its stage widths are not jit entries
+        assert favor_graph_search._cache_size() - compiled <= 1, gq
         rec[gq] = np.mean([refimpl.recall_at_k(res.ids[i], truth[i], 10)
                            for i in range(len(queries))])
     assert rec["pq"] >= rec[None] - 0.01, rec

@@ -5,8 +5,8 @@ import jax.numpy as jnp
 import pytest
 
 import repro.core.search as search_mod
-from repro.core import (FavorIndex, SearchConfig, compile_filter,
-                        favor_graph_search, graph_arrays, paper_filters,
+from repro.core import (FavorIndex, SearchConfig, SearchOptions,
+                        compile_filter, favor_graph_search, paper_filters,
                         rsf_graph_search, stack_programs)
 from repro.core import exclusion
 from repro.core import filters as F
@@ -64,7 +64,7 @@ def test_jax_matches_oracle_recall(small_index, small_dataset, queries, scenario
 def test_search_returns_only_targets(small_index, small_dataset, queries):
     vecs, attrs, schema = small_dataset
     flt, prog, mask = _setup(small_index, small_dataset, "equality_int")
-    res = small_index.search(queries, flt, k=10, ef=80)
+    res = small_index.query(queries, flt, SearchOptions(k=10, ef=80))
     for row in res.ids:
         for v in row[row >= 0]:
             assert mask[v], "non-target row leaked into S"
@@ -96,8 +96,10 @@ def test_termination_guard_improves_recall(small_index, small_dataset, queries):
     flt, prog, mask = _setup(small_index, small_dataset, "equality_int")
     k, ef = 10, 40
     r_guard, r_plain = [], []
-    res_g = small_index.search(queries, flt, k=k, ef=ef, pbar_min=0.5, force="graph")
-    res_p = small_index.search(queries, flt, k=k, ef=ef, pbar_min=0.0, force="graph")
+    res_g = small_index.query(queries, flt, SearchOptions(
+        k=k, ef=ef, pbar_min=0.5, force="graph"))
+    res_p = small_index.query(queries, flt, SearchOptions(
+        k=k, ef=ef, pbar_min=0.0, force="graph"))
     for i, q in enumerate(queries):
         t = _truth(vecs, mask, q, k)
         r_guard.append(refimpl.recall_at_k(res_g.ids[i], t, k))
@@ -122,7 +124,8 @@ def test_selector_routing(small_index, small_dataset, queries):
     vecs, attrs, schema = small_dataset
     lowsel = F.And(F.Equality("i0", 3), F.Range("f0", 10.0, 16.0))  # ~0.6%
     highsel = F.Equality("b0", True)  # 50%
-    res = small_index.search(queries[:8], [lowsel] * 4 + [highsel] * 4, k=5, ef=48)
+    res = small_index.query(queries[:8], [lowsel] * 4 + [highsel] * 4,
+                            SearchOptions(k=5, ef=48))
     assert res.routed_brute[:4].all(), f"low-sel not routed brute: {res.p_hat[:4]}"
     assert not res.routed_brute[4:].any()
 
@@ -130,14 +133,16 @@ def test_selector_routing(small_index, small_dataset, queries):
 def test_brute_route_exact(small_index, small_dataset, queries):
     vecs, attrs, schema = small_dataset
     flt, prog, mask = _setup(small_index, small_dataset, "logic")
-    res = small_index.search(queries, flt, k=10, ef=64, force="brute")
+    res = small_index.query(queries, flt,
+                            SearchOptions(k=10, ef=64, force="brute"))
     for i, q in enumerate(queries):
         t = _truth(vecs, mask, q, 10)
         assert refimpl.recall_at_k(res.ids[i], t, 10) == 1.0
 
 
 def test_empty_filter_returns_padding(small_index, queries):
-    res = small_index.search(queries[:4], F.FalseFilter(), k=5, ef=48)
+    res = small_index.query(queries[:4], F.FalseFilter(),
+                            SearchOptions(k=5, ef=48))
     assert (res.ids == -1).all()
 
 
@@ -147,8 +152,9 @@ def test_save_load_end2end(small_index, small_dataset, queries, tmp_path):
     small_index.save(p)
     fi2 = FavorIndex.load(p)
     flt = paper_filters(schema)["equality_bool"]
-    r1 = small_index.search(queries[:4], flt, k=5, ef=48)
-    r2 = fi2.search(queries[:4], flt, k=5, ef=48)
+    opts = SearchOptions(k=5, ef=48)
+    r1 = small_index.query(queries[:4], flt, opts)
+    r2 = fi2.query(queries[:4], flt, opts)
     np.testing.assert_array_equal(r1.ids, r2.ids)
 
 
