@@ -6,8 +6,10 @@ FAVOR's exclusion-distance mechanism (Eq. 2) is scorer-agnostic: it reshapes
 ``core.search`` therefore composes three orthogonal pieces per neighbor
 block:
 
+    side rows   -> (B, M, C) int32       (``side_table``: norm + attributes)
     score_block -> (B, M) distances      (this module: f32 / PQ-ADC / SQ)
-    filter eval -> (B, M) TD mask        (filters.eval_program_gathered)
+    filter eval -> (B, M) TD mask        (the Pallas exact kernel's own, else
+                                          filters.eval_program_gathered)
     exclusion   -> dbar = d + (1-td)*D   (``exclusion_compose`` below)
 
 A Scorer is a *frozen, array-free* dataclass so it can ride along as a
@@ -15,12 +17,16 @@ jit-static parameter (it is derived from the jit-static ``SearchConfig`` via
 ``scorer_for``); all device state lives in the ``g`` array dict and in the
 per-query ``state`` dict built once by ``prepare`` before the while_loop:
 
-    prepare(g, queries, programs) -> state      # e.g. the ADC LUTs (B, M, K)
-    score_block(g, state, ids)    -> (B, M) f32 # distances for gathered ids
+    prepare(g, queries, programs)    -> state   # e.g. the ADC LUTs (B, M, K)
+    score_block(g, state, ids, side) -> (d, td) # (B, M) f32 distances for
+                                                # the gathered ids, and the
+                                                # TD mask or None
 
 ``programs`` is threaded through ``prepare`` only so the Pallas exact path
-can reuse the fused gather_distance kernel (which evaluates the filter
-in-kernel); the jnp scorers ignore it.
+can reuse the fused gather_distance kernel, which evaluates the filter
+in-kernel: that scorer returns the block's TD mask, and the traversal
+evaluates the filter itself, on the same side rows, only where a scorer
+returns None.  Which of the two a scorer does is fixed by the scorer.
 
 Scorers return *distance-scale* values (sqrt of the squared forms) so the
 exclusion distance D -- calibrated in true-distance units from Delta_d --
@@ -60,6 +66,36 @@ def pairwise_dist(q: jnp.ndarray, vecs: jnp.ndarray,
     return jnp.sqrt(jnp.maximum(d2, 0.0))
 
 
+def side_table(g: dict) -> jnp.ndarray:
+    """(N, C) int32: each node's per-row scalars packed into one row --
+    its norm, its int attributes, its float attributes and, once the index
+    has tombstones, its ``alive`` flag -- with floats bitcast, so every
+    value comes back bit for bit.  A wave reads a neighbour block's scalars
+    as one row gather of this rank-2 table (a gather from a 1-D array is
+    several times slower per row on TPU)."""
+    cols = [jax.lax.bitcast_convert_type(
+                g["norms"].astype(jnp.float32), jnp.int32)[:, None],
+            g["attrs_int"].astype(jnp.int32),
+            jax.lax.bitcast_convert_type(
+                g["attrs_float"].astype(jnp.float32), jnp.int32)]
+    if "alive" in g:
+        cols.append(g["alive"].astype(jnp.int32)[:, None])
+    return jnp.concatenate(cols, axis=1)
+
+
+def side_fields(g: dict, rows: jnp.ndarray):
+    """Gathered side rows (..., C) -> (norms (...) f32, ints (..., mi),
+    floats (..., mf) f32, alive (...) bool or None), as ``side_table``
+    packed them from ``g``."""
+    mi, mf = g["attrs_int"].shape[1], g["attrs_float"].shape[1]
+    norms = jax.lax.bitcast_convert_type(rows[..., 0], jnp.float32)
+    ints = rows[..., 1:1 + mi]
+    floats = jax.lax.bitcast_convert_type(rows[..., 1 + mi:1 + mi + mf],
+                                          jnp.float32)
+    alive = rows[..., 1 + mi + mf] > 0 if "alive" in g else None
+    return norms, ints, floats, alive
+
+
 def exclusion_compose(d: jnp.ndarray, td: jnp.ndarray,
                       D: jnp.ndarray) -> jnp.ndarray:
     """Eq. 2: adjusted distance ``d + D`` for non-target rows, ``d`` for TD.
@@ -90,9 +126,12 @@ class Scorer(Protocol):
         """Per-query device state built once before the traversal loop."""
         ...
 
-    def score_block(self, g: dict, state: dict, ids) -> jnp.ndarray:
-        """(B, M) distances for the gathered DB rows ``ids`` (clamped >= 0;
-        masking of pad/visited entries stays in the traversal)."""
+    def score_block(self, g: dict, state: dict, ids, side=None) -> tuple:
+        """(d, td) for the gathered DB rows ``ids`` (clamped >= 0; masking
+        of pad/visited entries stays in the traversal): (B, M) distances,
+        and the block's (B, M) TD mask under the batch's filter programs,
+        or None where the scorer does not evaluate the filter.  ``side``
+        holds ``side_table(g)[ids]``; None gathers it here."""
         ...
 
     def bytes_per_row(self, g: dict) -> int:
@@ -105,8 +144,10 @@ class ExactScorer:
     """Full-precision float32 scoring (the seed behavior).
 
     ``use_pallas=True`` routes each neighbor block through the
-    kernels/gather_distance scalar-prefetch kernel (row DMAs picked by the
-    prefetched ids) instead of the jnp gather + mul/reduce.
+    kernels/gather_distance kernel instead of the jnp gather + mul/reduce,
+    and returns the TD mask the kernel evaluates beside the distances.
+    Both read the block's norms (and the kernel its attributes) from the
+    gathered side rows.
     """
     use_pallas: bool = False
     kind = "exact"
@@ -121,17 +162,20 @@ class ExactScorer:
             state["programs"] = programs
         return state
 
-    def score_block(self, g: dict, state: dict, ids) -> jnp.ndarray:
+    def score_block(self, g: dict, state: dict, ids, side=None) -> tuple:
+        if side is None:
+            side = side_table(g)[ids]
+        norms, ints, floats, _ = side_fields(g, side)
         if self.use_pallas:
             from ..kernels.gather_distance import ops as gd_ops
             # dvec=0 -> plain distances; the traversal owns the exclusion
-            # composition (and re-evaluates TD where it needs the mask)
-            d, _ = gd_ops.gather_distance(
-                g["vectors"], g["norms"], g["attrs_int"], g["attrs_float"],
-                state["q"], ids, state["programs"],
+            # composition, on the kernel's TD mask
+            d, td = gd_ops.gather_distance(
+                g["vectors"], norms, ints, floats, state["q"], ids,
+                state["programs"],
                 jnp.zeros((state["q"].shape[0],), jnp.float32))
-            return jnp.minimum(d, 3.0e38)  # keep +inf out of the pools' math
-        return pairwise_dist(state["q"], g["vectors"][ids], g["norms"][ids])
+            return jnp.minimum(d, 3.0e38), td  # keep +inf out of the pools
+        return pairwise_dist(state["q"], g["vectors"][ids], norms), None
 
     def bytes_per_row(self, g: dict) -> int:
         return 4 * int(g["vectors"].shape[1])
@@ -167,7 +211,7 @@ class PqAdcScorer:
             luts = luts.astype(jnp.bfloat16)
         return {"luts": luts}
 
-    def score_block(self, g: dict, state: dict, ids) -> jnp.ndarray:
+    def score_block(self, g: dict, state: dict, ids, side=None) -> tuple:
         luts = state["luts"]
         if self.use_pallas:
             from ..kernels.pq_adc import ops as pq_ops
@@ -188,7 +232,7 @@ class PqAdcScorer:
             adc2 = jnp.sum(gath.astype(jnp.float32), axis=-1)  # f32 accum
         # sqrt: ADC tables are squared sub-distances; the exclusion D and
         # the termination test live in true-distance units
-        return jnp.sqrt(jnp.maximum(adc2, 0.0))
+        return jnp.sqrt(jnp.maximum(adc2, 0.0)), None
 
     def bytes_per_row(self, g: dict) -> int:
         return int(g["codes"].shape[1])
@@ -240,7 +284,7 @@ class SqScorer:
                      - 2.0 * jnp.sum(q * lo[None, :], axis=-1),
         }
 
-    def score_block(self, g: dict, state: dict, ids) -> jnp.ndarray:
+    def score_block(self, g: dict, state: dict, ids, side=None) -> tuple:
         c = g["codes"][ids].astype(jnp.float32)               # (B, M, d)
         b, m0, d = c.shape
         quad = jax.lax.dot_general(
@@ -249,7 +293,7 @@ class SqScorer:
             preferred_element_type=jnp.float32).reshape(b, m0)
         lin = jnp.sum(c * state["w_lin"][:, None, :], axis=-1)
         d2 = quad + lin + state["const"][:, None]
-        return jnp.sqrt(jnp.maximum(d2, 0.0))
+        return jnp.sqrt(jnp.maximum(d2, 0.0)), None
 
     def bytes_per_row(self, g: dict) -> int:
         return int(g["codes"].shape[1])

@@ -52,7 +52,8 @@ import numpy as np
 
 from . import filters as F
 from .hnsw import HnswIndex
-from .scoring import exclusion_compose, pairwise_dist, scorer_for
+from .scoring import (exclusion_compose, pairwise_dist, scorer_for,
+                      side_fields, side_table)
 
 INF = jnp.inf
 
@@ -247,11 +248,13 @@ def _visit_bits(visited, rows, safe, mark):
 # ---------------------------------------------------------------------------
 # Traversal building blocks
 # ---------------------------------------------------------------------------
-def _descend(g: dict, queries: jnp.ndarray, scorer, sstate: dict) -> jnp.ndarray:
+def _descend(g: dict, side: jnp.ndarray, queries: jnp.ndarray, scorer,
+             sstate: dict) -> jnp.ndarray:
     """Upper-layer greedy descent (no filtering), returns entry ids (B,)."""
     B = queries.shape[0]
     cur = jnp.full((B,), g["entry"], jnp.int32)
-    curd = scorer.score_block(g, sstate, cur[:, None])[:, 0]
+    curd = scorer.score_block(g, sstate, cur[:, None],
+                              side[cur][:, None, :])[0][:, 0]
     n_upper = g["upper"].shape[0]
     for li in range(n_upper - 1, -1, -1):
         level = g["upper"][li]
@@ -265,7 +268,7 @@ def _descend(g: dict, queries: jnp.ndarray, scorer, sstate: dict) -> jnp.ndarray
             nbrs = level[cur]                      # (B, M)
             ok = nbrs >= 0
             safe = jnp.maximum(nbrs, 0)
-            d = scorer.score_block(g, sstate, safe)
+            d, _ = scorer.score_block(g, sstate, safe, side[safe])
             d = jnp.where(ok, d, INF)
             j = jnp.argmin(d, axis=1)
             best = jnp.take_along_axis(d, j[:, None], axis=1)[:, 0]
@@ -277,6 +280,16 @@ def _descend(g: dict, queries: jnp.ndarray, scorer, sstate: dict) -> jnp.ndarray
         cur, curd, _ = jax.lax.while_loop(
             cond, body, (cur, curd, jnp.ones((B,), bool)))
     return cur
+
+
+def _td(g: dict, programs: dict, rows, td=None):
+    """(B, M) TD mask of gathered side rows ``rows`` (B, M, C): the one a
+    scorer returned (the Pallas kernel's), else the batch's filter programs
+    evaluated on the rows' attributes; tombstoned rows are never TD."""
+    _, ints, floats, alive = side_fields(g, rows)
+    if td is None:
+        td = F.eval_program_gathered(programs, ints, floats, xp=jnp)
+    return td if alive is None else td & alive
 
 
 def _merge_pool(pool: tuple, new: tuple, cap: int) -> tuple:
@@ -329,12 +342,6 @@ def _graph_traverse(g: dict, queries: jnp.ndarray, programs: dict,
     ef, ccap = cfg.ef, cfg.ccap
     rows = jnp.arange(B)
 
-    # optional live-index tombstone mask (N,) bool: dead nodes stay routable
-    # (their edges still carry the walk) but are never admitted to R -- the
-    # key is absent until the first delete, so static indexes trace the
-    # exact pre-live program and stay bit-identical
-    alive = g.get("alive")
-
     # Device scopes at the traversal's seams (HLO op metadata, trace-time
     # only; results are unchanged): ``graph.init`` (descent, pools, visited
     # set), per wave ``wave.select`` / ``wave.visit`` / ``wave.score`` /
@@ -343,16 +350,20 @@ def _graph_traverse(g: dict, queries: jnp.ndarray, programs: dict,
     # traversal's time to them; none contains ``favor.``, which names the
     # Pallas kernels, each of which stays the innermost scope of its call.
     with jax.named_scope("graph.init"):
+        # every per-row scalar a wave reads, one int32 row per node: a wave
+        # fetches a block's with one row gather.  The optional live-index
+        # tombstone mask ``alive`` rides along: dead nodes stay routable
+        # (their edges still carry the walk) but are never TD, so never
+        # admitted to R -- the key is absent until the first delete, so
+        # static indexes trace the exact pre-live program
+        side = side_table(g)                             # (N, C)
         sstate = scorer.prepare(g, queries, programs)
-        ep = _descend(g, queries, scorer, sstate)        # (B,)
+        ep = _descend(g, side, queries, scorer, sstate)  # (B,)
 
         # --- init pools with the entry point -------------------------------
-        ep_d = scorer.score_block(g, sstate, ep[:, None])[:, 0]
-        ep_td = F.eval_program_gathered(
-            programs, g["attrs_int"][ep][:, None, :],
-            g["attrs_float"][ep][:, None, :], xp=jnp)[:, 0]
-        if alive is not None:
-            ep_td = ep_td & alive[ep]
+        ep_rows = side[ep][:, None, :]                   # (B, 1, C)
+        ep_d, ep_td = scorer.score_block(g, sstate, ep[:, None], ep_rows)
+        ep_d, ep_td = ep_d[:, 0], _td(g, programs, ep_rows, ep_td)[:, 0]
         ep_key = exclusion_compose(ep_d, ep_td, D)   # rsf: D = 0 -> plain d
         seed_ok = ep_td if rsf else jnp.ones((B,), bool)
 
@@ -430,14 +441,15 @@ def _graph_traverse(g: dict, queries: jnp.ndarray, programs: dict,
                 visited = _visit_bits(s["visited"], rows, safe, new)
 
             with jax.named_scope("wave.score"):
-                d = scorer.score_block(g, sstate, safe)
+                # the block's and the expanded node's side rows, each read
+                # once, as a row gather of the rank-2 side table
+                blk = side[safe]                     # (S, M0, C)
+                va_rows = side[va_safe][:, None, :]  # (S, 1, C)
+                d, td = scorer.score_block(g, sstate, safe, blk)
 
             with jax.named_scope("wave.filter"):
-                td = F.eval_program_gathered(
-                    programs, g["attrs_int"][safe], g["attrs_float"][safe],
-                    xp=jnp)
-                if alive is not None:
-                    td = td & alive[safe]
+                td = _td(g, programs, blk, td)       # the block's one eval
+                va_td = _td(g, programs, va_rows)[:, 0]
                 key = exclusion_compose(d, td, D[:, None])   # Eq. 2
 
             with jax.named_scope("wave.merge"):
@@ -453,13 +465,6 @@ def _graph_traverse(g: dict, queries: jnp.ndarray, programs: dict,
                     (cand_d, cand_i),
                     (jnp.where(eligible, key, INF),
                      jnp.where(eligible, nbrs, -1)), ccap)
-
-            with jax.named_scope("wave.filter"):
-                va_td = F.eval_program_gathered(
-                    programs, g["attrs_int"][va_safe][:, None, :],
-                    g["attrs_float"][va_safe][:, None, :], xp=jnp)[:, 0]
-                if alive is not None:
-                    va_td = va_td & alive[va_safe]
             return {
                 "cand_d": cand_d, "cand_i": cand_i,
                 "res_d": res_d, "res_i": res_i, "res_t": res_t,

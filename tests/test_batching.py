@@ -115,8 +115,9 @@ def test_gather_distance_valid_mask(small_index, small_dataset):
                                        dtype=np.int32))
     progs = _stacked(schema, [paper_filters(schema)["range_50"]] * b)
     dvec = jnp.zeros((b,), jnp.float32)
-    args = (g["vectors"], g["norms"], g["attrs_int"], g["attrs_float"],
-            queries, nbr_ids, progs, dvec)
+    safe = jnp.maximum(nbr_ids, 0)
+    args = (g["vectors"], g["norms"][safe], g["attrs_int"][safe],
+            g["attrs_float"][safe], queries, nbr_ids, progs, dvec)
     d0, td0 = gd_ops.gather_distance(*args)
     valid = np.array([True, False, True, False])
     d1, td1 = gd_ops.gather_distance(*args, valid=valid)

@@ -122,8 +122,10 @@ def test_gather_distance_sweep(n, d, b, m):
     progs = _progs(b, rng)
     nbrs = rng.integers(-1, n, size=(b, m)).astype(np.int32)  # includes -1 pads
     dvec = jnp.asarray(rng.uniform(0.0, 1.0, size=(b,)).astype(np.float32))
-    kd, ktd = gd_ops.gather_distance(vecs, norms, ints, floats, qs,
-                                     jnp.asarray(nbrs), progs, dvec)
+    safe = np.maximum(nbrs, 0)
+    kd, ktd = gd_ops.gather_distance(vecs, norms[safe], ints[safe],
+                                     floats[safe], qs, jnp.asarray(nbrs),
+                                     progs, dvec)
     rd, rtd = gd_ref.gather_distance_ref(jnp.asarray(nbrs), qs, vecs, norms,
                                          ints, floats, progs, dvec)
     rd_c = np.where(np.asarray(rd) >= gd_ref.BIG, np.inf, np.asarray(rd))

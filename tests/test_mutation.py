@@ -377,6 +377,58 @@ def test_merge_folds_to_equivalent_static_index(ds):
     assert int(ids[5]) not in gg.ids
 
 
+def test_graph_route_reads_fresh_side_rows_after_mutations(ds):
+    """The graph route reads each node's norm, attributes and alive flag
+    from packed side rows: after an attribute update, a delete and an
+    insert, a graph search filters on the new attributes, never admits
+    the dead row, and scores every row with its current norm."""
+    vecs, attrs, schema = ds
+    fi = FavorIndex.build(vecs, F.AttributeTable(
+        schema, attrs.ints.copy(), attrs.floats.copy()),
+        HnswParams(M=8, efc=48, seed=3))
+    be = LocalBackend(fi)
+    col = schema.int_index("i0")
+    flt = F.Equality("i0", 3)
+    opts = OPTS.with_(force="graph")
+    q = np.random.default_rng(71).normal(
+        size=(1, vecs.shape[1])).astype(np.float32)
+    order = _exact_topk(vecs, q, np.arange(len(vecs)), len(vecs))[0][0]
+    match = attrs.ints[order, col] == 3
+    upd = int(order[~match][0])        # nearest row the filter rejects
+    victim = int(order[match][0])      # nearest row the filter admits
+
+    def check(all_vecs, i0, alive):
+        r = router.execute(be, q, flt, opts)
+        ids = r.ids[0][r.ids[0] >= 0]
+        assert (i0[ids] == 3).all() and alive[ids].all()
+        exact = np.linalg.norm(all_vecs[ids] - q[0], axis=1)
+        # |q|^2 + |v|^2 - 2 q.v in float32: ~1e-5 of cancellation near 0
+        np.testing.assert_allclose(r.dists[0][:len(ids)], exact,
+                                   rtol=1e-5, atol=1e-4)
+        return ids
+
+    # attribute update, in place: the attributes epoch re-uploads them
+    fi.attrs.ints[upd, col] = 3
+    fi.bump_version(("attributes",))
+    i0 = fi.attrs.ints[:, col].copy()
+    alive = np.ones(len(vecs), bool)
+    assert upd in check(vecs, i0, alive)
+    # delete: a tombstone, so the graph's side rows gain an alive flag
+    assert be.delete([victim]) == 1
+    alive[victim] = False
+    ids = check(vecs, i0, alive)
+    assert victim not in ids and upd in ids
+    # insert, merged into the graph: new vectors, norms and attributes
+    ints, floats = _matching_attrs(attrs, schema)
+    new_vec = (q + 0.1).astype(np.float32)
+    nid = int(be.upsert(new_vec, ints, floats)[0])
+    be.merge(wave=256)
+    all_vecs = np.concatenate([vecs, new_vec])
+    ids = check(all_vecs, np.concatenate([i0, ints[:, col]]),
+                np.concatenate([alive, [True]]))
+    assert ids[0] == nid and upd in ids and victim not in ids
+
+
 # ---------------------------------------------------------------------------
 # edge cases
 # ---------------------------------------------------------------------------

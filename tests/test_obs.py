@@ -759,3 +759,49 @@ def test_wave_merge_compiles_without_sort_or_gather(small_index):
                                    line).group(1))
     assert merge_ops, "no compiled op carries wave.merge"
     assert not merge_ops & {"sort", "gather"}, merge_ops
+
+
+@pytest.mark.parametrize("use_pallas", [False, True])
+def test_wave_reads_block_scalars_as_one_rank2_gather(small_index,
+                                                      use_pallas):
+    """A wave reads its neighbour block's per-row scalars once: no gather
+    of the compiled traversal carries the ``wave.filter`` scope, and every
+    gather under ``wave.score`` reads a rank-2 operand -- the vector rows
+    (N, d) or the packed side rows (N, 1 + mi + mf) -- so XLA has not split
+    the side rows back into 1-D gathers of norms and attributes."""
+    opts = SearchOptions(k=5, ef=32, use_pallas=use_pallas)
+    b = 8
+    g = small_index.g
+    schema = small_index.attrs.schema
+    programs = router.compile_programs(_flt(schema), schema, b)
+    text = favor_graph_search.lower(
+        g, jnp.asarray(_queries(b, 16)), programs,
+        jnp.zeros((b,), jnp.float32), opts.search_config(),
+        valid=jnp.ones((b,), bool)).compile().as_text()
+    # "%name = <type>[<dims>]{layout} <opcode>(operands), ..., metadata={...}"
+    defn = re.compile(r"\s*(?:ROOT\s+)?(%[\w.\-]+)\s*=\s*\w+\[([\d,]*)\]")
+    shapes = {}
+    for line in text.splitlines():
+        m = defn.match(line)
+        if m:
+            shapes[m.group(1)] = tuple(int(x) for x in m.group(2).split(",")
+                                       if x)
+    n, dim = g["vectors"].shape
+    side = (n, 1 + g["attrs_int"].shape[1] + g["attrs_float"].shape[1])
+    read, scopes = set(), set()
+    for line in text.splitlines():
+        name = re.search(r'op_name="([^"]*)"', line)
+        if not name:
+            continue
+        for scope in ("wave.score", "wave.filter"):
+            if f"{scope}/" in name.group(1):
+                scopes.add(scope)
+        if not re.search(r"\sgather\(", line):
+            continue
+        assert "wave.filter/" not in name.group(1), line
+        if "wave.score/" in name.group(1):
+            operand = re.search(r"\sgather\((?:\w+\[[\d,]*\]\S*\s+)?"
+                                r"(%[\w.\-]+)", line).group(1)
+            read.add(shapes[operand])
+    assert scopes == {"wave.score", "wave.filter"}
+    assert read == {(n, dim), side}, read

@@ -2,6 +2,7 @@
 traversal's scorer parity (ADC graph within 1pt of f32; bit-identical with
 lossless codes), the rsf lane-mask alignment and the graph_arrays memo."""
 import numpy as np
+import jax
 import jax.numpy as jnp
 import pytest
 
@@ -87,7 +88,9 @@ def test_score_block_matches_true_distance(scorer):
     qs = jnp.asarray(rng.normal(size=(4, vecs.shape[1])).astype(np.float32))
     ids = jnp.asarray(rng.integers(0, vecs.shape[0], size=(4, 16), dtype=np.int32))
     state = scorer.prepare(gs, qs, _progs(4))
-    d = np.asarray(scorer.score_block(gs, state, ids))
+    d, td = scorer.score_block(gs, state, ids)
+    assert td is None          # the jnp scorers leave the filter to the traversal
+    d = np.asarray(d)
     true = np.linalg.norm(np.asarray(qs)[:, None, :] - vecs[np.asarray(ids)],
                           axis=-1)
     if scorer.exact:
@@ -131,8 +134,9 @@ if HAVE_HYPOTHESIS:
         ids = rng.integers(0, vecs.shape[0], size=(b, m), dtype=np.int32)
         perm = rng.permutation(m)
         state = scorer.prepare(gs, qs, _progs(b))
-        d = np.asarray(scorer.score_block(gs, state, jnp.asarray(ids)))
-        dp = np.asarray(scorer.score_block(gs, state, jnp.asarray(ids[:, perm])))
+        d = np.asarray(scorer.score_block(gs, state, jnp.asarray(ids))[0])
+        dp = np.asarray(
+            scorer.score_block(gs, state, jnp.asarray(ids[:, perm]))[0])
         np.testing.assert_array_equal(d[:, perm], dp)
 
     @settings(max_examples=25, deadline=None)
@@ -240,8 +244,8 @@ def test_pq_bf16_lut_tolerance():
     assert st_lo["luts"].dtype == jnp.bfloat16
     assert st_hi["luts"].dtype == jnp.float32
     assert lo.lut_bytes(g, 4) * 2 == hi.lut_bytes(g, 4)
-    d_lo = np.asarray(lo.score_block(g, st_lo, ids))
-    d_hi = np.asarray(hi.score_block(g, st_hi, ids))
+    d_lo = np.asarray(lo.score_block(g, st_lo, ids)[0])
+    d_hi = np.asarray(hi.score_block(g, st_hi, ids)[0])
     np.testing.assert_allclose(d_lo, d_hi, rtol=2e-2)
     assert np.mean(np.abs(d_lo - d_hi) / (d_hi + 1e-6)) < 5e-3
 
@@ -257,12 +261,12 @@ def test_sq_score_block_bit_stable_across_batch_width():
     ids = jnp.asarray(rng.integers(0, vecs.shape[0], size=(8, 16),
                                    dtype=np.int32))
     full_state = sc.prepare(gs, qs, _progs(8))
-    full = np.asarray(sc.score_block(gs, full_state, ids))
+    full = np.asarray(sc.score_block(gs, full_state, ids)[0])
     for width in (1, 2, 4):
         for off in range(0, 8, width):
             sl = slice(off, off + width)
             st = sc.prepare(gs, qs[sl], _progs(width))
-            part = np.asarray(sc.score_block(gs, st, ids[sl]))
+            part = np.asarray(sc.score_block(gs, st, ids[sl])[0])
             np.testing.assert_array_equal(part, full[sl])
 
 
@@ -379,6 +383,123 @@ def test_graph_route_pallas_scorers_match_jnp():
         rp = router.execute(be, qs, flt, base.with_(use_pallas=True))
         np.testing.assert_array_equal(rj.ids, rp.ids), gq
         np.testing.assert_allclose(rj.dists, rp.dists, rtol=1e-5, atol=1e-5)
+
+
+# ---------------------------------------------------------------------------
+# Side rows: one read of a block's scalars, one filter evaluation
+# ---------------------------------------------------------------------------
+# attribute values sit exactly on the range's bounds, and the largest int
+# code is one the filter asks for; two DNF terms, so W > 1
+SIDE_FILTER = F.Or(
+    F.And(F.Inclusion("i0", [2, 9]), F.Range("f0", 10.0, 60.0)),
+    F.And(F.Equality("b0", True), F.Range("f0", 60.0, 85.0)))
+
+
+@pytest.fixture(scope="module")
+def side_corpus():
+    from repro import quant
+    rng = np.random.default_rng(23)
+    n, d = 400, 16
+    vecs = rng.normal(size=(n, d)).astype(np.float32)
+    attrs = random_attributes(SCHEMA, n, seed=24)
+    i0 = SCHEMA.int_columns.index(
+        next(c for c in SCHEMA.int_columns if c.name == "i0"))
+    attrs.ints[::5, i0] = 9                       # the vocabulary's last code
+    attrs.floats[::7, 0] = 10.0                   # on flo
+    attrs.floats[3::7, 0] = 60.0                  # on fhi of one term, flo
+    attrs.floats[5::7, 0] = 85.0                  # of the other; fhi
+    fi = FavorIndex.build(vecs, attrs, HnswParams(M=6, efc=32, seed=2))
+    g = dict(fi.g)
+    pq = quant.train_pq(vecs, m=4, nbits=6, iters=5, seed=1)
+    sq = quant.train_sq(vecs)
+    g["codes"] = jnp.asarray(quant.encode(pq, vecs))
+    g["centroids"] = jnp.asarray(pq.centroids)
+    g["sq_codes"] = jnp.asarray(quant.encode(sq, vecs))
+    g["sq_lo"] = jnp.asarray(sq.lo)
+    g["sq_scale"] = jnp.asarray(sq.scale)
+    queries = jnp.asarray(rng.normal(size=(4, d)).astype(np.float32))
+    return g, fi.delta_d, queries
+
+
+def _two_read_oracle(monkeypatch):
+    """The traversal as it read a block's scalars before the side rows:
+    each norm, attribute and alive flag through a gather of its own array
+    at the row ids, and the filter evaluated by eval_program_gathered
+    whatever the scorer returns.  The "side row" becomes the row id."""
+    from repro.core import scoring as scoring_mod
+    from repro.core import search as search_mod
+
+    def table(g):
+        return jnp.arange(g["norms"].shape[0], dtype=jnp.int32)[:, None]
+
+    def fields(g, rows):
+        ids = rows[..., 0]
+        return (g["norms"][ids], g["attrs_int"][ids], g["attrs_float"][ids],
+                g["alive"][ids] if "alive" in g else None)
+
+    evaluate = search_mod._td
+    monkeypatch.setattr(search_mod, "side_table", table)
+    monkeypatch.setattr(search_mod, "side_fields", fields)
+    monkeypatch.setattr(scoring_mod, "side_fields", fields)
+    monkeypatch.setattr(search_mod, "_td",
+                        lambda g, programs, rows, td=None:
+                        evaluate(g, programs, rows))
+    return search_mod._graph_traverse
+
+
+@pytest.mark.parametrize("tombstones", [False, True], ids=["static", "dead"])
+@pytest.mark.parametrize("rsf", [False, True], ids=["favor", "rsf"])
+@pytest.mark.parametrize("kind", ["exact", "exact_pallas", "pq", "sq"])
+def test_side_rows_traversal_bit_parity(side_corpus, monkeypatch, kind, rsf,
+                                        tombstones):
+    """Reading a block's norms, attributes and alive flags from the packed
+    side rows, and taking the Pallas kernel's TD mask, leave every result
+    of the traversal bit for bit as the separate reads and the second
+    filter evaluation gave, for every scorer, mode and tombstone state.
+    The Pallas kernel's mask equals eval_program_gathered on the block."""
+    g0, delta_d, q = side_corpus
+    cfg = {"exact": SearchConfig(k=5, ef=24),
+           "exact_pallas": SearchConfig(k=5, ef=24, use_pallas=True),
+           "pq": SearchConfig(k=5, ef=24, graph_quant="pq"),
+           "sq": SearchConfig(k=5, ef=24, graph_quant="sq")}[kind]
+    scorer = scorer_for(cfg)
+    g = dict(_g_for(g0, scorer))          # the fixture's dict stays as is
+    if tombstones:
+        alive = np.ones(g["norms"].shape[0], bool)
+        alive[1::3] = False
+        g["alive"] = jnp.asarray(alive)
+    b = q.shape[0]
+    progs = _progs(b, SIDE_FILTER)
+    sel = float(np.mean(np.asarray(F.eval_program_gathered(
+        progs, g["attrs_int"][None], g["attrs_float"][None], xp=jnp))))
+    assert 0.1 < sel < 0.5
+    if rsf:
+        D = jnp.zeros((b,), jnp.float32)
+        got = rsf_graph_search(g, q, progs, cfg)
+    else:
+        from repro.core import exclusion
+        D = jnp.full((b,), exclusion.exclusion_distance(
+            sel, cfg.ef, delta_d), jnp.float32)
+        got = favor_graph_search(g, q, progs, D, cfg)
+
+    if kind == "exact_pallas":
+        ids = jnp.asarray(np.random.default_rng(5).integers(
+            0, g["norms"].shape[0], size=(b, 32), dtype=np.int32))
+        _, td = scorer.score_block(g, scorer.prepare(g, q, progs), ids)
+        want_td = F.eval_program_gathered(progs, g["attrs_int"][ids],
+                                          g["attrs_float"][ids], xp=jnp)
+        np.testing.assert_array_equal(np.asarray(td), np.asarray(want_td))
+        assert 0 < int(np.sum(np.asarray(td))) < td.size
+
+    traverse = _two_read_oracle(monkeypatch)
+    # a fresh jit traces the body again, with the oracle's reads in it
+    want = jax.jit(lambda g, q, p, D: traverse(
+        g, q, p, D, cfg, scorer, None, rsf=rsf))(g, q, progs, D)
+    assert int(np.asarray(want["waves"])[0]) > 1
+    assert (np.asarray(want["ids"]) >= 0).any()
+    for key in ("ids", "dists", "hops", "path_td", "waves"):
+        np.testing.assert_array_equal(np.asarray(got[key]),
+                                      np.asarray(want[key]), err_msg=key)
 
 
 # ---------------------------------------------------------------------------

@@ -82,11 +82,12 @@ def test_pq_adc_topr_compiles_for_v5e(spec):
 
 
 def test_gather_distance_compiles_for_v5e(spec):
-    norms, ints, floats = _db(spec)
+    # the block's per-row scalars come gathered, (B, M0) and (B, M0, m)
     _assert_kernel(
         lambda v, n, i, f, q, ids, p, dv: gd_ops.gather_distance(
             v, n, i, f, q, ids, p, dv, interpret=False),
-        spec((N, D), jnp.float32), norms, ints, floats,
+        spec((N, D), jnp.float32), spec((B, M0), jnp.float32),
+        spec((B, M0, MI), jnp.int32), spec((B, M0, MF), jnp.float32),
         spec((B, D), jnp.float32), spec((B, M0), jnp.int32),
         _programs(spec), spec((B,), jnp.float32))
 
